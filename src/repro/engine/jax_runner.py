@@ -1,6 +1,7 @@
 """Live JAX execution backend: the same Engine/tick loop, but ``run_batch``
-really runs jit'd prefill/decode steps of a (reduced) model on this host and
-returns wall-clock seconds.
+really runs jit'd prefill/decode steps of a model on this host's device (a
+published-width config on a TPU, a reduced one on the CPU) and returns
+wall-clock seconds.
 
 Two cache layouts, behind one ``_CacheLayout`` strategy surface:
 
@@ -55,7 +56,8 @@ from repro.models import model_zoo
 from repro.models.config import ModelConfig
 from repro.models.transformer import (KVCache, PagedKVCache, lm_decode_paged,
                                       lm_mixed_paged, lm_prefill_paged,
-                                      lm_step, supports_paged)
+                                      lm_step, prefill_next_token,
+                                      supports_paged)
 
 
 def _bucket(n: int, lo: int = 32) -> int:
@@ -352,11 +354,10 @@ class _PagedLayout(_CacheLayout):
 
         def _prefill(params, cache, tokens, positions, table, wpid, woff,
                      kv_len, last_idx):
-            logits, cache = lm_prefill_paged(cfg, params, cache, tokens,
+            hidden, cache = lm_prefill_paged(cfg, params, cache, tokens,
                                              positions, table, wpid, woff,
-                                             kv_len)
-            nxt = jnp.argmax(logits[0, last_idx], axis=-1).astype(jnp.int32)
-            return nxt, cache
+                                             kv_len, return_hidden=True)
+            return prefill_next_token(cfg, params, hidden, last_idx), cache
 
         def _copy_page(cache, src, dst):
             return PagedKVCache(cache.k.at[:, dst].set(cache.k[:, src]),

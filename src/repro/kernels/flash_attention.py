@@ -5,8 +5,8 @@ GQA, and chunked-prefill query offsets. Online-softmax accumulation runs in
 VMEM scratch across the innermost (sequential) kv-block grid dimension;
 block shapes are MXU/VREG aligned (multiples of (8,128) in f32).
 
-The online-softmax block update (``online_softmax_block`` /
-``online_softmax_finish``) is shared with the paged variant in
+The online-softmax block update (``online_softmax_init`` /
+``online_softmax_block``) is shared with the paged variant in
 ``paged_flash_attention.py`` — the two kernels differ only in how KV blocks
 reach VMEM (contiguous grid stride here, scalar-prefetched block-table
 indirection there) and in how the mask is built.
@@ -35,11 +35,14 @@ F32 = jnp.float32
 NEG_INF = -1e30
 
 
-def online_softmax_block(s, v, m_scr, l_scr, acc_scr):
+def online_softmax_block(s, v, m_scr, l_scr, acc_scr, *,
+                         pv_dims=(((2,), (0,)), ((), ()))):
     """One online-softmax accumulation step over a scored KV block.
 
     s: (G, bq, bk) f32 masked scores; v: (bk, D) f32;
     scratch: m/l (G, bq, 1) f32, acc (G, bq, D) f32 — updated in place.
+    ``pv_dims`` is the ``p @ v`` contraction (the paged kernel batches its
+    KV heads: s (Hkv, rows, page), v (page, Hkv, D)).
     """
     m_prev = m_scr[...]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -47,8 +50,7 @@ def online_softmax_block(s, v, m_scr, l_scr, acc_scr):
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((), ())),
-                             preferred_element_type=F32)
+    pv = jax.lax.dot_general(p, v, pv_dims, preferred_element_type=F32)
     acc_scr[...] = acc_scr[...] * alpha + pv
     m_scr[...] = m_new
 

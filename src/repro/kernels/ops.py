@@ -1,8 +1,9 @@
 """Public kernel entry points with platform dispatch.
 
-On TPU the Pallas kernels run compiled (``interpret=False``); everywhere else
-they run in interpret mode or fall back to the jnp oracle. Model code calls
-these wrappers, never ``pl.pallas_call`` directly.
+On TPU the Pallas kernels run compiled (each kernel's ``interpret=None``
+default resolves to compiled there); everywhere else the jnp oracle runs
+unless ``use_kernel=True`` asks for the kernel in interpret mode. Model code
+calls these wrappers, never ``pl.pallas_call`` directly.
 
     attention(...)         prefill/train attention (flash kernel | oracle)
     prefill_attention(...) gather-free paged prefill (paged flash | oracle)
@@ -12,7 +13,6 @@ these wrappers, never ``pl.pallas_call`` directly.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -27,20 +27,10 @@ from repro.kernels.paged_flash_attention import (paged_flash_attention as
 from repro.kernels.wkv6 import wkv6 as _wkv6
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                              # pragma: no cover
-        return False
-
-
 def _use_kernels(override: Optional[bool]) -> bool:
     if override is not None:
         return override
-    env = os.environ.get("REPRO_USE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    return _on_tpu()
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
@@ -49,7 +39,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D)."""
     if _use_kernels(use_kernel):
         return _flash(q, k, v, causal=causal, window=window, softcap=softcap,
-                      q_offset=q_offset, interpret=not _on_tpu())
+                      q_offset=q_offset)
     return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                     softcap=softcap, q_offset=q_offset)
 
@@ -66,8 +56,7 @@ def prefill_attention(q, k_pages, v_pages, block_table, kv_len, q_offset, *,
     """
     if _use_kernels(use_kernel):
         return _paged_flash(q, k_pages, v_pages, block_table, kv_len,
-                            q_offset, block_q=block_q,
-                            interpret=not _on_tpu())
+                            q_offset, block_q=block_q)
     return _ref.paged_flash_attention_ref(q, k_pages, v_pages, block_table,
                                           kv_len, q_offset)
 
@@ -87,10 +76,8 @@ def decode_attention(q, k_pages, v_pages, block_table, lengths, *,
     if _use_kernels(use_kernel):
         if fused:
             return _paged_fused(q, k_pages, v_pages, block_table, lengths,
-                                k_new, v_new, write_pages, write_offsets,
-                                interpret=not _on_tpu())
-        return _paged(q, k_pages, v_pages, block_table, lengths,
-                      interpret=not _on_tpu())
+                                k_new, v_new, write_pages, write_offsets)
+        return _paged(q, k_pages, v_pages, block_table, lengths)
     if fused:
         k_pages = k_pages.at[write_pages, write_offsets].set(k_new)
         v_pages = v_pages.at[write_pages, write_offsets].set(v_new)
@@ -103,6 +90,5 @@ def decode_attention(q, k_pages, v_pages, block_table, lengths, *,
 def wkv(r, k, v, w, u, state, *, chunk: int = 32,
         use_kernel: Optional[bool] = None):
     if _use_kernels(use_kernel):
-        return _wkv6(r, k, v, w, u, state, chunk=chunk,
-                     interpret=not _on_tpu())
+        return _wkv6(r, k, v, w, u, state, chunk=chunk)
     return _ref.wkv6_ref(r, k, v, w, u, state)

@@ -76,7 +76,9 @@ def _fused_kernel(table_ref, len_ref, wp_ref, wo_ref, q_ref, k_ref, v_ref,
                   m_scr, l_scr, acc_scr, k_sem, v_sem, *, scale: float,
                   page: int, n_pages: int):
     """Grid: (B, max_pages). q_ref/o_ref: (Hkv, G, D); k/v_ref: (page, Hkv,
-    D) steered by the table; kn/vn_ref: (Hkv, D) the new token's KV;
+    D) steered by the table; kn/vn_ref: (1, Hkv, D) the new token's KV (the
+    unit row axis is kept, not squeezed, so the prologue DMA's source and
+    its (1, Hkv, D) pool-slot target agree in rank when compiled);
     kp/vp_out: the pool in HBM (ANY space, aliased to the blocked k/v page
     inputs — same underlying buffers, written via dynamic async copy).
 
@@ -94,10 +96,9 @@ def _fused_kernel(table_ref, len_ref, wp_ref, wo_ref, q_ref, k_ref, v_ref,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        kcp = pltpu.make_async_copy(
-            kn_ref, kp_out.at[wp_ref[b], wo_ref[b]], k_sem)
-        vcp = pltpu.make_async_copy(
-            vn_ref, vp_out.at[wp_ref[b], wo_ref[b]], v_sem)
+        slot = (pl.ds(wp_ref[b], 1), wo_ref[b])
+        kcp = pltpu.make_async_copy(kn_ref, kp_out.at[slot], k_sem)
+        vcp = pltpu.make_async_copy(vn_ref, vp_out.at[slot], v_sem)
         kcp.start()
         vcp.start()
         kcp.wait()
@@ -126,7 +127,7 @@ def _fused_kernel(table_ref, len_ref, wp_ref, wo_ref, q_ref, k_ref, v_ref,
     def _finish():
         # the new token, straight from VMEM: one more online-softmax step
         # over a single-entry kv block at position lengths[b] - 1
-        kn = kn_ref[...].astype(F32)                      # (Hkv, D)
+        kn = kn_ref[0].astype(F32)                        # (Hkv, D)
         s_new = jax.lax.dot_general(q, kn, (((2,), (1,)), ((0,), (0,))),
                                     preferred_element_type=F32)[..., None]
         m_prev2 = m_scr[...]
@@ -135,7 +136,7 @@ def _fused_kernel(table_ref, len_ref, wp_ref, wo_ref, q_ref, k_ref, v_ref,
         alpha2 = jnp.exp(m_prev2 - m_fin)
         l_fin = alpha2 * l_scr[...] + p_new
         acc = acc_scr[...] * alpha2 + \
-            p_new * vn_ref[...].astype(F32)[:, None, :]
+            p_new * vn_ref[0].astype(F32)[:, None, :]
         o_ref[...] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
@@ -179,8 +180,8 @@ def paged_attention_fused(q, k_pages, v_pages, block_table, lengths, k_new,
             pl.BlockSpec((None, Hkv, G, D), q_map),
             pl.BlockSpec((None, page, Hkv, D), kv_map),
             pl.BlockSpec((None, page, Hkv, D), kv_map),
-            pl.BlockSpec((None, Hkv, D), new_map),
-            pl.BlockSpec((None, Hkv, D), new_map),
+            pl.BlockSpec((1, Hkv, D), new_map),
+            pl.BlockSpec((1, Hkv, D), new_map),
         ],
         out_specs=[
             pl.BlockSpec((None, Hkv, G, D), q_map),

@@ -11,21 +11,26 @@ per chunk. Per chunk the read side touches ``Np * page`` tokens of KV once
 accounts.
 
 Grid and table indirection
-    grid = (B, Hkv, n_q_blocks, Np) with ``num_scalar_prefetch=3``
-    (``block_table (B, Np)``, ``kv_len (B,)``, ``q_offset (B,)``). The KV
-    BlockSpec index_map returns ``(table[b, j], 0, h, 0)`` — the scalar
+    grid = (B, n_q_blocks, Np) with ``num_scalar_prefetch=3``
+    (``block_table (B, Np)``, ``kv_len (B,)``, ``q_offset (B,)``). One grid
+    step holds every query head of one q block, ``(Hkv, G, block_q, D)``,
+    and one pool page of every KV head, ``(page, Hkv, D)`` — a block whose
+    last two dims equal the pool's own, as the TPU (8, 128) tiling rule
+    requires, and which fetches each page once for all heads. The KV
+    BlockSpec index_map returns ``(table[b, j], 0, 0, 0)`` — the scalar
     prefetch happens before the grid runs, so the DMA engine can steer
     every page fetch directly off the table with no device round trip. The
     innermost (page) dimension is sequential: online-softmax state for the
     current q block lives in VMEM scratch across it, exactly as in
-    ``flash_attention``, whose block-update helpers this kernel reuses.
+    ``flash_attention``, whose block-update helpers this kernel reuses
+    (batched over the KV heads, as the decode kernel's dot products are).
 
 VMEM scratch budget
-    m/l: 2 * (G, block_q, 1) f32 and acc: (G, block_q, D) f32 per core —
-    for G=4, block_q=128, D=128 that is ~264 KiB, plus the pipelined
-    q/k/v/o blocks ((G*block_q + 2*page + G*block_q) * D * itemsize);
-    comfortably inside the ~16 MiB/core budget for every config in
-    ``configs/`` (the page size of 32 keeps a (page, D) tile VREG-aligned).
+    m/l: 2 * (Hkv, G * block_q, 1) f32 and acc: (Hkv, G * block_q, D) f32
+    — for Hq=16, block_q=128, D=128 that is 5 MiB once m/l pad their unit
+    last dim to 128 lanes, plus the double-buffered q/o blocks (2 MiB in
+    bf16) and k/v pages; the TPU compiler accepts it at Qwen2.5-3B widths
+    (``tests/test_tpu_compile.py``).
 
 Masking rules
     * **causality**: queries sit at absolute kv positions ``q_offset[b] +
@@ -58,43 +63,49 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import (NEG_INF, F32, online_softmax_block,
-                                           online_softmax_finish,
                                            online_softmax_init)
 
 
 def _kernel(table_ref, len_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float, page: int, block_q: int,
             n_pages: int):
-    """Grid: (B, Hkv, n_q_blocks, Np).
+    """Grid: (B, n_q_blocks, Np).
 
-    q_ref/o_ref: (G, block_q, D); k_ref/v_ref: (page, D) — one pool page of
-    one KV head, steered by the prefetched table; scratch as in flash.
+    q_ref/o_ref: (Hkv, G, block_q, D) — every query head of one q block,
+    flattened in-kernel to rows ``g * block_q + i``; k_ref/v_ref: (page,
+    Hkv, D) — one pool page of every KV head, steered by the prefetched
+    table; scratch: m/l (Hkv, G * block_q, 1) f32, acc (Hkv, G * block_q, D)
+    f32.
     """
     b = pl.program_id(0)
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         online_softmax_init(m_scr, l_scr, acc_scr)
 
-    q = q_ref[...].astype(F32) * scale            # (G, bq, D)
-    k = k_ref[...].astype(F32)                    # (page, D)
-    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                            preferred_element_type=F32)   # (G, bq, page)
+    Hkv, G, _, D = q_ref.shape
+    q = q_ref[...].astype(F32).reshape(Hkv, G * block_q, D) * scale
+    k = k_ref[...].astype(F32)                    # (page, Hkv, D)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
+                            preferred_element_type=F32)   # (Hkv, G*bq, page)
 
-    q_pos = qoff_ref[b] + qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_q, page), 1)
+    rows = s.shape[1]
+    q_pos = qoff_ref[b] + qi * block_q + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (1, rows, page), 1), block_q)
     kv_pos = j * page + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_q, page), 2)
+        jnp.int32, (1, rows, page), 2)
     mask = (kv_pos <= q_pos) & (kv_pos < len_ref[b])
     s = jnp.where(mask, s, NEG_INF)
 
-    online_softmax_block(s, v_ref[...].astype(F32), m_scr, l_scr, acc_scr)
+    online_softmax_block(s, v_ref[...].astype(F32), m_scr, l_scr, acc_scr,
+                         pv_dims=(((2,), (0,)), ((0,), (1,))))
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        online_softmax_finish(o_ref, m_scr, l_scr, acc_scr)
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = o.reshape(Hkv, G, block_q, D).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
@@ -122,27 +133,31 @@ def paged_flash_attention(q, k_pages, v_pages, block_table, kv_len, q_offset,
     if Sq_pad != Sq:            # ragged final q block: pad, slice off below
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Sq_pad - Sq), (0, 0)))
     n_q = Sq_pad // block_q
-    qg = q.reshape(B, Hkv, G, Sq_pad, D)
+    qg = q.reshape(B, Hkv, G, n_q, block_q, D)
 
-    def q_map(b, h, i, j, table, kl, qo):
-        return (b, h, 0, i, 0)
+    def q_map(b, i, j, table, kl, qo):
+        return (b, 0, 0, i, 0, 0)
 
-    def kv_map(b, h, i, j, table, kl, qo):
-        return (table[b, j], 0, h, 0)
+    def kv_map(b, i, j, table, kl, qo):
+        return (table[b, j], 0, 0, 0)
 
+    # one grid step holds every head of a q block and one page of every KV
+    # head: the (page, Hkv, D) block equals the pool's own minor dims, as
+    # the TPU (8, 128) tiling rule requires, and each page is fetched once
+    rows = G * block_q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, n_q, Np),
+        grid=(B, n_q, Np),
         in_specs=[
-            pl.BlockSpec((None, None, G, block_q, D), q_map),
-            pl.BlockSpec((None, page, None, D), kv_map),
-            pl.BlockSpec((None, page, None, D), kv_map),
+            pl.BlockSpec((None, Hkv, G, None, block_q, D), q_map),
+            pl.BlockSpec((None, page, Hkv, D), kv_map),
+            pl.BlockSpec((None, page, Hkv, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, None, G, block_q, D), q_map),
+        out_specs=pl.BlockSpec((None, Hkv, G, None, block_q, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G, block_q, 1), F32),
-            pltpu.VMEM((G, block_q, 1), F32),
-            pltpu.VMEM((G, block_q, D), F32),
+            pltpu.VMEM((Hkv, rows, 1), F32),
+            pltpu.VMEM((Hkv, rows, 1), F32),
+            pltpu.VMEM((Hkv, rows, D), F32),
         ],
     )
     kernel = functools.partial(_kernel, scale=D ** -0.5, page=page,
@@ -150,7 +165,7 @@ def paged_flash_attention(q, k_pages, v_pages, block_table, kv_len, q_offset,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Sq_pad, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
     )(block_table, kv_len, q_offset, qg, k_pages, v_pages)
     out = out.reshape(B, Hq, Sq_pad, D)

@@ -14,6 +14,7 @@ TARGET is TPU; validated on CPU with ``interpret=True`` against
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,11 +68,16 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref, s_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, w, u, state, *, chunk: int = 32, interpret: bool = True):
+def wkv6(r, k, v, w, u, state, *, chunk: int = 32,
+         interpret: Optional[bool] = None):
     """r/k/v/w: (B, T, H, K) [w in (0,1)]; u: (H, K); state: (B, H, K, K) f32.
 
     Returns (o (B, T, H, K) f32, final state (B, H, K, K) f32).
+    ``interpret=None`` resolves by backend: compiled on TPU, interpreter
+    elsewhere.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, T, H, K = r.shape
     chunk = min(chunk, T)
     assert T % chunk == 0, (T, chunk)

@@ -29,18 +29,6 @@ from repro.models.layers import (ParallelCtx, apply_norm, attention, attn_out,
 F32 = jnp.float32
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` landed (with ``check_vma``) in newer JAX; older
-    releases only ship ``jax.experimental.shard_map.shard_map`` (with the
-    equivalent ``check_rep`` flag)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
@@ -120,10 +108,10 @@ def _moe_block(cfg: ModelConfig, lp, h, pctx: Optional[ParallelCtx]):
         ep, tp = pctx.ep_axis, pctx.tp_axis
         wspec = {"router": P(), "w_gate": P(ep, None, tp), "w_up": P(ep, None, tp),
                  "w_down": P(ep, tp, None)}
-        fn = _shard_map(
+        fn = jax.shard_map(
             partial(moe_ffn_ep_local, cfg, ep_axis=ep, tp_axis=tp),
             mesh=pctx.mesh, in_specs=(wspec, P(dp, None, None)),
-            out_specs=P(dp, None, None))
+            out_specs=P(dp, None, None), check_vma=False)
         return fn(lp["moe"], h)
     import os
     token_shard = "moe_replicated" in os.environ.get("REPRO_OPT", "")
@@ -375,7 +363,8 @@ def lm_decode_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
 
 def lm_prefill_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
                      positions, table, write_pages, write_offsets, kv_len, *,
-                     pctx: Optional[ParallelCtx] = None):
+                     pctx: Optional[ParallelCtx] = None,
+                     return_hidden: bool = False):
     """Chunked prefill of ONE sequence against the page pool, gather-free.
 
     tokens/positions: (1, C) — absolute positions; padded lanes sit at
@@ -394,7 +383,9 @@ def lm_prefill_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
     ``lm_prefill_paged_gather`` pays). Exact semantics: queries at absolute
     positions, causal over the previously cached (possibly *shared*) prefix
     + the chunk itself, stale/scratch slots masked by ``kv_len``.
-    Returns (logits (1, C, V), cache).
+    Returns (logits (1, C, V), cache), or with ``return_hidden`` the final
+    normed hidden states (1, C, D) in place of the logits — callers that
+    need one position's logits unembed just that row.
     """
     assert supports_paged(cfg), "paged prefill: unsupported attention variant"
     x = _embed(cfg, params, tokens)                   # (1, C, D)
@@ -427,8 +418,17 @@ def lm_prefill_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
 
     x, (ks, vs) = _uscan(body, x, (params["layers"], cache.k, cache.v))
     x = apply_norm(cfg, params["ln_final"], x)
-    logits = _unembed(cfg, params, x)
-    return logits, PagedKVCache(ks, vs)
+    if return_hidden:
+        return x, PagedKVCache(ks, vs)
+    return _unembed(cfg, params, x), PagedKVCache(ks, vs)
+
+
+def prefill_next_token(cfg: ModelConfig, params, hidden, last_idx):
+    """Greedy next token after a prefill chunk: unembeds only row
+    ``last_idx`` of ``hidden`` (1, C, D) — the (C, V) logits of the whole
+    chunk would be the step's largest temporary."""
+    logits = _unembed(cfg, params, hidden[0, last_idx])
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def lm_mixed_paged(cfg: ModelConfig, params, cache: PagedKVCache,
@@ -467,11 +467,10 @@ def lm_mixed_paged(cfg: ModelConfig, params, cache: PagedKVCache,
 
     def pack_body(carry, inp):
         toks, pos, table, wpid, woff, kv_len, last = inp
-        logits, carry = lm_prefill_paged(cfg, params, carry, toks, pos,
+        hidden, carry = lm_prefill_paged(cfg, params, carry, toks, pos,
                                          table, wpid, woff, kv_len,
-                                         pctx=pctx)
-        nxt = jnp.argmax(logits[0, last], axis=-1).astype(jnp.int32)
-        return carry, nxt
+                                         pctx=pctx, return_hidden=True)
+        return carry, prefill_next_token(cfg, params, hidden, last)
 
     if P > 0:
         cache, p_next = lax.scan(

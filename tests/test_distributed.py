@@ -108,7 +108,8 @@ cfg = get_config("dbrx-132b").reduced()
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
     cfg.moe, capacity_factor=float(cfg.moe.num_experts) / cfg.moe.top_k))
 assert cfg.moe.num_experts % 2 == 0
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = init_moe(cfg, jax.random.PRNGKey(1), jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, cfg.d_model), jnp.float32)
 want = moe_ffn(cfg, p, x)
@@ -152,7 +153,8 @@ opt_state = init_opt(params)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 1, cfg.vocab_size)
 batch = {"tokens": toks, "targets": jnp.roll(toks, -1, axis=1)}
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 fn_m = build_train_step(cfg, mesh, remat=False)
 with mesh:
     p_m, o_m, m_m = jax.jit(fn_m)(params, opt_state, batch)

@@ -46,7 +46,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.launch.dryrun import run_cell
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 r = run_cell("llama3.2-1b", "decode_32k", mesh=mesh, verbose=False)
 assert r["status"] == "ok", r.get("error")
 assert r["flops"] > 0 and r["collectives"]["total"] >= 0

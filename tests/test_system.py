@@ -101,6 +101,29 @@ def test_live_jax_engine_end_to_end():
         assert len(s.ttfts) == 2
 
 
+def test_serve_live_reduced_serves_every_session():
+    """The chip smoke's serving path at the toy size: paged layout, every
+    session finishes all three rounds with its 32 + 32 + 64 tokens."""
+    from repro.launch.serve import serve_live
+    finished, eng = serve_live(n_sessions=2, reduced=True, verbose=False)
+    assert eng.backend.layout == "paged"
+    assert sorted(len(s.meta["generated"]) for s in finished) == [128, 128]
+    assert all(len(s.ttfts) == 3 for s in finished)
+
+
+def test_chip_smoke_exits_nonzero_without_tpu():
+    """On the CPU the chip smoke refuses to run and prints no result."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+
+
 def test_training_loss_decreases():
     from repro.launch.train import train
     losses, _ = train("llama3.2-1b", reduced=True, steps=30, seq_len=64,
