@@ -1,0 +1,20 @@
+"""Peak rates of one chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/
+v5e): 197 TFLOP/s in bf16 and 819 GB/s of HBM bandwidth per chip. A kind
+that is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
