@@ -27,6 +27,7 @@ from repro.configs.shapes import ShapeSpec
 from repro.distributed import sharding as sh
 from repro.models import model_zoo
 from repro.models.config import ModelConfig
+from repro.obs import scopes
 from repro.train.optimizer import OptConfig, OptState, adamw_update
 
 F32 = jnp.float32
@@ -104,11 +105,13 @@ def cross_entropy(logits, targets):
     """Vocab-sharding-friendly CE: the target logit is extracted with an
     iota-compare masked reduce (elementwise on the sharded vocab dim + psum)
     instead of take_along_axis, which GSPMD would all-gather."""
-    lf = logits.astype(F32)
-    lse = jax.scipy.special.logsumexp(lf, axis=-1)
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
-    tgt = jnp.sum(jnp.where(vocab_iota == targets[..., None], lf, 0.0), axis=-1)
-    return jnp.mean(lse - tgt)
+    with jax.named_scope(scopes.LOSS):
+        lf = logits.astype(F32)
+        lse = jax.scipy.special.logsumexp(lf, axis=-1)
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
+        tgt = jnp.sum(jnp.where(vocab_iota == targets[..., None], lf, 0.0),
+                      axis=-1)
+        return jnp.mean(lse - tgt)
 
 
 def chunked_cross_entropy(cfg: ModelConfig, params, hidden, targets,
@@ -129,11 +132,13 @@ def chunked_cross_entropy(cfg: ModelConfig, params, hidden, targets,
 
     def body(acc, xs):
         h, t = xs
-        lg = _unembed(cfg, params, h).astype(F32)
-        lse = jax.scipy.special.logsumexp(lg, axis=-1)
-        iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
-        tgt = jnp.sum(jnp.where(iota == t[..., None], lg, 0.0), axis=-1)
-        return acc + jnp.sum(lse - tgt), None
+        lg = _unembed(cfg, params, h)
+        with jax.named_scope(scopes.LOSS):
+            lg = lg.astype(F32)
+            lse = jax.scipy.special.logsumexp(lg, axis=-1)
+            iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
+            tgt = jnp.sum(jnp.where(iota == t[..., None], lg, 0.0), axis=-1)
+            return acc + jnp.sum(lse - tgt), None
 
     total, _ = _lax.scan(body, jnp.zeros((), F32), (hs, ts))
     return total / (B * S)

@@ -25,6 +25,7 @@ from repro.models.layers import (ParallelCtx, apply_norm, attention, attn_out,
                                  init_moe, init_norm, mha, mlp, moe_ffn,
                                  moe_ffn_ep_local, paged_decode_attention,
                                  paged_prefill_attention)
+from repro.obs import scopes
 
 F32 = jnp.float32
 
@@ -118,46 +119,72 @@ def _moe_block(cfg: ModelConfig, lp, h, pctx: Optional[ParallelCtx]):
     return moe_ffn(cfg, lp["moe"], h, pctx, token_shard=token_shard)
 
 
+def _attn_block(cfg: ModelConfig, lp, x, q_pos, attend):
+    """Attention sublayer under the ``attn`` scope: ln_attn, QKV,
+    ``attend(q, k, v) -> (o, kv)`` (the attention with any KV write), the
+    output projection and the residual add. Returns (x, kv)."""
+    with jax.named_scope(scopes.ATTN):
+        h = apply_norm(cfg, lp["ln_attn"], x)
+        q, k, v = attn_qkv(cfg, lp["attn"], h, q_pos)
+        o, kv = attend(q, k, v)
+        o = attn_out(lp["attn"], o)
+        if cfg.post_sublayer_norm:
+            o = apply_norm(cfg, lp["ln_post_attn"], o)
+        return x + o, kv
+
+
+def _mlp_block(cfg: ModelConfig, lp, x, pctx):
+    """MLP (or MoE) sublayer under the ``mlp`` scope: ln_mlp through the
+    residual add."""
+    with jax.named_scope(scopes.MLP):
+        h = apply_norm(cfg, lp["ln_mlp"], x)
+        if cfg.family == "moe":
+            f = _moe_block(cfg, lp, h, pctx)
+        else:
+            f = mlp(cfg, lp["mlp"], h, pctx)
+        if cfg.post_sublayer_norm:
+            f = apply_norm(cfg, lp["ln_post_mlp"], f)
+        return x + f
+
+
 def _layer_full(cfg: ModelConfig, x, lp, is_local, positions, pctx):
     """Full-sequence layer (train / prefill). Returns (x, (k, v))."""
-    h = apply_norm(cfg, lp["ln_attn"], x)
-    q, k, v = attn_qkv(cfg, lp["attn"], h, positions)
-    o = attention(q, k, v, positions, positions, causal=True,
-                  window=cfg.sliding_window, is_local=is_local,
-                  softcap=cfg.attn_logit_softcap)
-    o = attn_out(lp["attn"], o)
-    if cfg.post_sublayer_norm:
-        o = apply_norm(cfg, lp["ln_post_attn"], o)
-    x = x + o
+    def attend(q, k, v):
+        o = attention(q, k, v, positions, positions, causal=True,
+                      window=cfg.sliding_window, is_local=is_local,
+                      softcap=cfg.attn_logit_softcap)
+        return o, (k, v)
+
+    x, kv = _attn_block(cfg, lp, x, positions, attend)
     x = constrain(x, pctx, pctx.dp_spec if pctx else None, None, None)
-    h2 = apply_norm(cfg, lp["ln_mlp"], x)
-    if cfg.family == "moe":
-        f = _moe_block(cfg, lp, h2, pctx)
-    else:
-        f = mlp(cfg, lp["mlp"], h2, pctx)
-    if cfg.post_sublayer_norm:
-        f = apply_norm(cfg, lp["ln_post_mlp"], f)
-    x = x + f
+    x = _mlp_block(cfg, lp, x, pctx)
     x = constrain(x, pctx, pctx.dp_spec if pctx else None, None, None)
-    return x, (k, v)
+    return x, kv
 
 
 def _embed(cfg: ModelConfig, params, tokens, embeds=None):
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    if embeds is not None:   # modality-stub tokens are prepended
-        x = jnp.concatenate([embeds.astype(x.dtype), x], axis=1)
-    return x
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"][tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        if embeds is not None:   # modality-stub tokens are prepended
+            x = jnp.concatenate([embeds.astype(x.dtype), x], axis=1)
+        return x
+
+
+def _final_norm(cfg: ModelConfig, params, x):
+    with jax.named_scope(scopes.HEAD):
+        return apply_norm(cfg, params["ln_final"], x)
 
 
 def _unembed(cfg: ModelConfig, params, x):
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("...d,dv->...v", x, w, preferred_element_type=F32)
-    if cfg.final_logit_softcap is not None:
-        c = cfg.final_logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope(scopes.HEAD):
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("...d,dv->...v", x, w, preferred_element_type=F32)
+        if cfg.final_logit_softcap is not None:
+            c = cfg.final_logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 # ---------------------------------------------------------------------------
@@ -183,7 +210,7 @@ def lm_forward(cfg: ModelConfig, params, tokens, *, pctx: Optional[ParallelCtx] 
     body_fn = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable) \
         if remat else body
     x, (ks, vs) = _uscan(body_fn, x, (params["layers"], kinds))
-    x = apply_norm(cfg, params["ln_final"], x)
+    x = _final_norm(cfg, params, x)
     if return_hidden:
         return x
     logits = _unembed(cfg, params, x)
@@ -227,29 +254,20 @@ def lm_step(cfg: ModelConfig, params, cache: KVCache, tokens, positions, *,
 
     def body(x, scanned):
         lp, is_local, k_l, v_l = scanned
-        h = apply_norm(cfg, lp["ln_attn"], x)
-        q, k_new, v_new = attn_qkv(cfg, lp["attn"], h, q_pos)
-        k_l = k_l.at[b_idx, positions].set(k_new)
-        v_l = v_l.at[b_idx, positions].set(v_new)
-        o = attention(q, k_l, v_l, q_pos, kv_pos, kv_valid=kv_valid,
-                      causal=True, window=cfg.sliding_window,
-                      is_local=is_local, softcap=cfg.attn_logit_softcap)
-        o = attn_out(lp["attn"], o)
-        if cfg.post_sublayer_norm:
-            o = apply_norm(cfg, lp["ln_post_attn"], o)
-        x = x + o
-        h2 = apply_norm(cfg, lp["ln_mlp"], x)
-        if cfg.family == "moe":
-            f = _moe_block(cfg, lp, h2, pctx)
-        else:
-            f = mlp(cfg, lp["mlp"], h2, pctx)
-        if cfg.post_sublayer_norm:
-            f = apply_norm(cfg, lp["ln_post_mlp"], f)
-        x = x + f
-        return x, (k_l, v_l)
+
+        def attend(q, k_new, v_new):
+            k = k_l.at[b_idx, positions].set(k_new)
+            v = v_l.at[b_idx, positions].set(v_new)
+            o = attention(q, k, v, q_pos, kv_pos, kv_valid=kv_valid,
+                          causal=True, window=cfg.sliding_window,
+                          is_local=is_local, softcap=cfg.attn_logit_softcap)
+            return o, (k, v)
+
+        x, kv = _attn_block(cfg, lp, x, q_pos, attend)
+        return _mlp_block(cfg, lp, x, pctx), kv
 
     x, (ks, vs) = _uscan(body, x, (params["layers"], kinds, cache.k, cache.v))
-    x = apply_norm(cfg, params["ln_final"], x)
+    x = _final_norm(cfg, params, x)
     logits = _unembed(cfg, params, x)
     return logits, KVCache(ks, vs)
 
@@ -332,31 +350,22 @@ def lm_decode_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
 
     def body(x, scanned):
         lp, k_l, v_l = scanned                        # k/v_l: (P, page, H, D)
-        h = apply_norm(cfg, lp["ln_attn"], x)
-        q, k_new, v_new = attn_qkv(cfg, lp["attn"], h, q_pos)
-        # KV write fused into the attention dispatch (kernel prologue on
-        # the Pallas path; scatter-then-attend on the jnp oracle path —
-        # bitwise the old separate-scatter math)
-        o, k_l, v_l = paged_decode_attention(
-            q[:, 0], k_l, v_l, block_tables, lengths,
-            k_new=k_new[:, 0], v_new=v_new[:, 0],
-            write_pages=write_pages, write_offsets=write_offsets)
-        o = attn_out(lp["attn"], o[:, None])
-        if cfg.post_sublayer_norm:
-            o = apply_norm(cfg, lp["ln_post_attn"], o)
-        x = x + o
-        h2 = apply_norm(cfg, lp["ln_mlp"], x)
-        if cfg.family == "moe":
-            f = _moe_block(cfg, lp, h2, pctx)
-        else:
-            f = mlp(cfg, lp["mlp"], h2, pctx)
-        if cfg.post_sublayer_norm:
-            f = apply_norm(cfg, lp["ln_post_mlp"], f)
-        x = x + f
-        return x, (k_l, v_l)
+
+        def attend(q, k_new, v_new):
+            # KV write fused into the attention dispatch (kernel prologue on
+            # the Pallas path; scatter-then-attend on the jnp oracle path —
+            # bitwise the old separate-scatter math)
+            o, k, v = paged_decode_attention(
+                q[:, 0], k_l, v_l, block_tables, lengths,
+                k_new=k_new[:, 0], v_new=v_new[:, 0],
+                write_pages=write_pages, write_offsets=write_offsets)
+            return o[:, None], (k, v)
+
+        x, kv = _attn_block(cfg, lp, x, q_pos, attend)
+        return _mlp_block(cfg, lp, x, pctx), kv
 
     x, (ks, vs) = _uscan(body, x, (params["layers"], cache.k, cache.v))
-    x = apply_norm(cfg, params["ln_final"], x)
+    x = _final_norm(cfg, params, x)
     logits = _unembed(cfg, params, x[:, 0])
     return logits, PagedKVCache(ks, vs)
 
@@ -397,27 +406,18 @@ def lm_prefill_paged(cfg: ModelConfig, params, cache: PagedKVCache, tokens,
 
     def body(x, scanned):
         lp, k_l, v_l = scanned                        # k/v_l: (P, page, H, D)
-        h = apply_norm(cfg, lp["ln_attn"], x)
-        q, k_new, v_new = attn_qkv(cfg, lp["attn"], h, q_pos)
-        k_l = k_l.at[write_pages, write_offsets].set(k_new[0])
-        v_l = v_l.at[write_pages, write_offsets].set(v_new[0])
-        o = paged_prefill_attention(q, k_l, v_l, table_b, kv_len_b, q_offset)
-        o = attn_out(lp["attn"], o)
-        if cfg.post_sublayer_norm:
-            o = apply_norm(cfg, lp["ln_post_attn"], o)
-        x = x + o
-        h2 = apply_norm(cfg, lp["ln_mlp"], x)
-        if cfg.family == "moe":
-            f = _moe_block(cfg, lp, h2, pctx)
-        else:
-            f = mlp(cfg, lp["mlp"], h2, pctx)
-        if cfg.post_sublayer_norm:
-            f = apply_norm(cfg, lp["ln_post_mlp"], f)
-        x = x + f
-        return x, (k_l, v_l)
+
+        def attend(q, k_new, v_new):
+            k = k_l.at[write_pages, write_offsets].set(k_new[0])
+            v = v_l.at[write_pages, write_offsets].set(v_new[0])
+            o = paged_prefill_attention(q, k, v, table_b, kv_len_b, q_offset)
+            return o, (k, v)
+
+        x, kv = _attn_block(cfg, lp, x, q_pos, attend)
+        return _mlp_block(cfg, lp, x, pctx), kv
 
     x, (ks, vs) = _uscan(body, x, (params["layers"], cache.k, cache.v))
-    x = apply_norm(cfg, params["ln_final"], x)
+    x = _final_norm(cfg, params, x)
     if return_hidden:
         return x, PagedKVCache(ks, vs)
     return _unembed(cfg, params, x), PagedKVCache(ks, vs)
@@ -473,18 +473,20 @@ def lm_mixed_paged(cfg: ModelConfig, params, cache: PagedKVCache,
         return carry, prefill_next_token(cfg, params, hidden, last)
 
     if P > 0:
-        cache, p_next = lax.scan(
-            pack_body, cache,
-            (p_tokens, p_positions, p_tables, p_write_pages,
-             p_write_offsets, p_kv_lens, p_last_idx))
+        with jax.named_scope(scopes.PREFILL):
+            cache, p_next = lax.scan(
+                pack_body, cache,
+                (p_tokens, p_positions, p_tables, p_write_pages,
+                 p_write_offsets, p_kv_lens, p_last_idx))
     else:
         p_next = jnp.zeros((0,), jnp.int32)
     if B > 0:
-        logits, cache = lm_decode_paged(cfg, params, cache, d_tokens,
-                                        d_positions, d_tables, d_lengths,
-                                        d_write_pages, d_write_offsets,
-                                        pctx=pctx)
-        d_next = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope(scopes.DECODE):
+            logits, cache = lm_decode_paged(cfg, params, cache, d_tokens,
+                                            d_positions, d_tables, d_lengths,
+                                            d_write_pages, d_write_offsets,
+                                            pctx=pctx)
+            d_next = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         d_next = jnp.zeros((0,), jnp.int32)
     return p_next, d_next, cache
@@ -582,21 +584,15 @@ def lm_decode_windowed(cfg: ModelConfig, params, cache: WindowedKVCache,
         params["layers"])
 
     def sublayer(x, lp, k_l, v_l, kv_pos, kv_valid, write_pos):
-        h = apply_norm(cfg, lp["ln_attn"], x)
-        q, k_new, v_new = attn_qkv(cfg, lp["attn"], h, q_pos)
-        k_l = k_l.at[b_idx, write_pos].set(k_new[:, 0])
-        v_l = v_l.at[b_idx, write_pos].set(v_new[:, 0])
-        o = attention(q, k_l, v_l, q_pos, kv_pos, kv_valid=kv_valid,
-                      causal=True, softcap=cfg.attn_logit_softcap)
-        o = attn_out(lp["attn"], o)
-        if cfg.post_sublayer_norm:
-            o = apply_norm(cfg, lp["ln_post_attn"], o)
-        x = x + o
-        h2 = apply_norm(cfg, lp["ln_mlp"], x)
-        f = mlp(cfg, lp["mlp"], h2, pctx)
-        if cfg.post_sublayer_norm:
-            f = apply_norm(cfg, lp["ln_post_mlp"], f)
-        return x + f, k_l, v_l
+        def attend(q, k_new, v_new):
+            k = k_l.at[b_idx, write_pos].set(k_new[:, 0])
+            v = v_l.at[b_idx, write_pos].set(v_new[:, 0])
+            o = attention(q, k, v, q_pos, kv_pos, kv_valid=kv_valid,
+                          causal=True, softcap=cfg.attn_logit_softcap)
+            return o, (k, v)
+
+        x, (k_l, v_l) = _attn_block(cfg, lp, x, q_pos, attend)
+        return _mlp_block(cfg, lp, x, pctx), k_l, v_l
 
     def body(x, scanned):
         lp_pair, kl, vl, kg, vg = scanned
@@ -610,7 +606,7 @@ def lm_decode_windowed(cfg: ModelConfig, params, cache: WindowedKVCache,
     x, (kl, vl, kg, vg) = _uscan(
         body, x, (pair_params, cache.k_loc, cache.v_loc,
                   cache.k_glob, cache.v_glob))
-    x = apply_norm(cfg, params["ln_final"], x)
+    x = _final_norm(cfg, params, x)
     logits = _unembed(cfg, params, x[:, 0])
     return logits, WindowedKVCache(kl, vl, kg, vg)
 

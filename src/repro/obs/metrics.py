@@ -237,10 +237,3 @@ def bind_engine_probes(reg: MetricsRegistry, engine) -> None:
     # it directly (dropped > 0 voids the exclusive-timeline invariant — see
     # obs.detect's event_loss incident and trace_report --strict)
     reg.gauge("events.dropped").set_fn(lambda: float(engine.bus.dropped))
-
-
-def bind_router_probe(reg: MetricsRegistry, router) -> None:
-    """Absorb the cluster router's membership/placement/requeue counters
-    and heartbeat-digest prefix stats."""
-    reg.register_probe("router", router.stats)
-    reg.register_probe("cluster_prefix", router.cluster_prefix_stats)

@@ -13,6 +13,8 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 F32 = jnp.float32
 
 
@@ -56,29 +58,31 @@ def global_norm(tree) -> jax.Array:
 
 def adamw_update(cfg: OptConfig, params, grads, state: OptState):
     """Returns (new_params, new_state, metrics)."""
-    step = state.step
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))
-    b1, b2 = cfg.betas
-    lr = lr_at(cfg, step)
-    bc1 = 1.0 - b1 ** (step.astype(F32) + 1.0)
-    bc2 = 1.0 - b2 ** (step.astype(F32) + 1.0)
+    with jax.named_scope(scopes.ADAMW):
+        step = state.step
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))
+        b1, b2 = cfg.betas
+        lr = lr_at(cfg, step)
+        bc1 = 1.0 - b1 ** (step.astype(F32) + 1.0)
+        bc2 = 1.0 - b2 ** (step.astype(F32) + 1.0)
 
-    def upd(p, g, m, v):
-        g = g.astype(F32) * scale
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * jnp.square(g)
-        mh = m / bc1
-        vh = v / bc2
-        step_ = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(F32)
-        return (p.astype(F32) - lr * step_).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g = g.astype(F32) * scale
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * jnp.square(g)
+            mh = m / bc1
+            vh = v / bc2
+            step_ = (mh / (jnp.sqrt(vh) + cfg.eps)
+                     + cfg.weight_decay * p.astype(F32))
+            return (p.astype(F32) - lr * step_).astype(p.dtype), m, v
 
-    out = jax.tree.map(upd, params, grads, state.mu, state.nu)
-    new_params = jax.tree.map(lambda t: t[0], out,
+        out = jax.tree.map(upd, params, grads, state.mu, state.nu)
+        new_params = jax.tree.map(lambda t: t[0], out,
+                                  is_leaf=lambda x: isinstance(x, tuple))
+        new_mu = jax.tree.map(lambda t: t[1], out,
                               is_leaf=lambda x: isinstance(x, tuple))
-    new_mu = jax.tree.map(lambda t: t[1], out,
-                          is_leaf=lambda x: isinstance(x, tuple))
-    new_nu = jax.tree.map(lambda t: t[2], out,
-                          is_leaf=lambda x: isinstance(x, tuple))
-    return new_params, OptState(step + 1, new_mu, new_nu), \
-        {"grad_norm": gnorm, "lr": lr}
+        new_nu = jax.tree.map(lambda t: t[2], out,
+                              is_leaf=lambda x: isinstance(x, tuple))
+        return new_params, OptState(step + 1, new_mu, new_nu), \
+            {"grad_norm": gnorm, "lr": lr}
