@@ -191,6 +191,13 @@ def _unembed(cfg: ModelConfig, params, x):
 # forward: full sequence (train / prefill)
 # ---------------------------------------------------------------------------
 
+# What a recomputed layer keeps for the backward pass: the outputs of its
+# products without batch dimensions (the q/k/v/o projections, the MLP's
+# gate and up). The attention scores, softmax, norms, RoPE and SiLU are
+# recomputed; so are an MoE layer's expert products (an expert batch dim).
+REMAT_POLICY = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
+
 def lm_forward(cfg: ModelConfig, params, tokens, *, pctx: Optional[ParallelCtx] = None,
                embeds=None, positions=None, return_cache: bool = False,
                remat: bool = False, return_hidden: bool = False):
@@ -207,8 +214,7 @@ def lm_forward(cfg: ModelConfig, params, tokens, *, pctx: Optional[ParallelCtx] 
         lp, is_local = scanned
         return _layer_full(cfg, x, lp, is_local, positions, pctx)
 
-    body_fn = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable) \
-        if remat else body
+    body_fn = jax.checkpoint(body, policy=REMAT_POLICY) if remat else body
     x, (ks, vs) = _uscan(body_fn, x, (params["layers"], kinds))
     x = _final_norm(cfg, params, x)
     if return_hidden:
