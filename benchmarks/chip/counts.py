@@ -2,15 +2,18 @@
 dispatched (real packs, lanes and context lengths), never from the padded
 buckets: the same work counts the same whatever implements it.
 
-``c`` is a configuration (``configs/<name>.json``). Attention counts two
-multiply-adds per (query head, key) pair and head dim: Q.K and P.V. Bytes
-are the least the kernel must move: the keys and values it reads once,
-its queries in and outputs out, and the new keys and values it writes, in
-bf16 (2 bytes).
+``c`` is a configuration (``configs/<name>.json``); the weights a token
+multiplies in a layer come from its architecture module
+(``layer_matmul_params``). Attention counts two multiply-adds per (query
+head, key) pair and head dim: Q.K and P.V. Bytes are the least the kernel
+must move: the keys and values it reads once, its queries in and outputs
+out, and the new keys and values it writes, in bf16 (2 bytes).
 """
 from __future__ import annotations
 
 from typing import Iterable, Tuple
+
+import model as bench_model
 
 BYTES = 2
 
@@ -49,12 +52,6 @@ def prefill_attention(c: dict, packs: Iterable[Tuple[int, int]]
     return L * flops, L * bytes_
 
 
-def layer_matmul_params(c: dict) -> int:
-    D, F = c["hidden_size"], c["intermediate_size"]
-    H, Hkv, Dh, _ = _dims(c)
-    return D * H * Dh + 2 * D * Hkv * Dh + H * Dh * D + 3 * D * F
-
-
 def step_flops(c: dict, packs, kv_lens) -> float:
     """Model operations one fused step needs: every token through every
     layer's matrix products and attention, and the unembedding of the rows
@@ -62,7 +59,7 @@ def step_flops(c: dict, packs, kv_lens) -> float:
     packs, kv_lens = list(packs), list(kv_lens)
     L = c["num_hidden_layers"]
     tokens = sum(ch for _, ch in packs) + len(kv_lens)
-    dense = 2.0 * L * layer_matmul_params(c) * tokens
+    dense = 2.0 * L * bench_model.arch(c).layer_matmul_params(c) * tokens
     unembed = 2.0 * c["hidden_size"] * c["vocab_size"] * (len(packs)
                                                           + len(kv_lens))
     return (dense + unembed + prefill_attention(c, packs)[0]
@@ -76,7 +73,7 @@ def train_step_flops(c: dict, batch: int, seq_len: int) -> float:
     tokens. Recomputation in the backward pass is not counted."""
     H, _Hkv, Dh, L = _dims(c)
     tokens = batch * seq_len
-    dense = 2.0 * (L * layer_matmul_params(c)
+    dense = 2.0 * (L * bench_model.arch(c).layer_matmul_params(c)
                    + c["hidden_size"] * c["vocab_size"]) * tokens
     pairs = batch * seq_len * (seq_len + 1) / 2
     return 3.0 * (dense + 4.0 * H * Dh * pairs * L)

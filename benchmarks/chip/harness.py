@@ -91,7 +91,7 @@ class System:
         from repro.models import model_zoo
         self.c = c
         self.e = c["engine"]
-        self.cfg = model_cfg or bench_model.program_config(c)
+        self.cfg = model_cfg or bench_model.arch(c).program_config(c)
         params = bench_model.make_params(c, seed)
         with mock.patch.object(model_zoo, "init",
                                lambda cfg, key, dtype: params):

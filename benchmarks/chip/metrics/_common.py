@@ -60,3 +60,14 @@ def roofline(flops, bytes_, seconds, pk):
         return None
     t_min = max(flops / pk["bf16_flops"], bytes_ / pk["hbm_bytes_per_s"])
     return 100.0 * t_min / seconds
+
+
+def scope_ms(ctx, group):
+    """Device milliseconds per traced train step in ``scopes.GROUPS[group]``,
+    both phases (``scopes.group_ms`` of the window's split); ``None``
+    without a split or where none of the group's scopes ran."""
+    import scopes
+    split = ctx.get("scope_split")
+    if not split or not any(sc in scopes.GROUPS[group] for sc, _ in split):
+        return None
+    return scopes.group_ms(split)[group]

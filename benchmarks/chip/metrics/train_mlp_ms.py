@@ -1,0 +1,8 @@
+"""Device milliseconds per traced train step, forward and backward, in the
+scope ``mlp``: the MLP (or expert layer), from its norm to its residual
+add."""
+from metrics._common import scope_ms
+
+
+def read(w, ctx):
+    return scope_ms(ctx, "train_mlp_ms")

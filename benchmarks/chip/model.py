@@ -2,7 +2,10 @@
 
 ``configs/<name>.json`` holds the published config's keys (Hugging Face
 names) as the benchmark runs them, the engine settings that every cell of
-the configuration shares, and the name of its plain reference.
+the configuration shares, and under ``reference`` the name of its
+architecture: the module ``arch/<reference>.py``, which gives what
+differs between architectures (the program's config, a layer's weights
+and their products, the plain reference layer).
 
 Weights are random and made on the device in one jitted call, in the
 type they are run in (bf16 served, float32 trained), in the program's
@@ -12,6 +15,7 @@ get the very same values.
 """
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 
@@ -28,22 +32,21 @@ def load(name: str) -> dict:
         cfg = json.load(f)
     if cfg.get("name") != name:
         raise ValueError(f"{path}: name {cfg.get('name')!r} != {name!r}")
+    arch(cfg)
     return cfg
 
 
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for configuration ``c``."""
-    from repro.models.config import ModelConfig
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"{c['name']}: only silu gated MLPs are served")
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=float(c["rope_theta"]), qkv_bias=c["attention_bias"],
-        tie_embeddings=c["tie_word_embeddings"],
-        norm_eps=float(c["rms_norm_eps"]), act="silu", gated_mlp=True)
+def arch(c: dict):
+    """The architecture module that configuration ``c`` names by its
+    ``reference`` key: ``arch/<reference>.py``."""
+    name = c["reference"]
+    try:
+        return importlib.import_module(f"arch.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"arch.{name}":
+            raise
+        raise ValueError(f"{c['name']}: no architecture {name!r}, expected "
+                         f"{HERE / 'arch' / (name + '.py')}") from None
 
 
 def root_key(seed: int):
@@ -57,33 +60,6 @@ def _normal(jax, jnp, key, shape, scale, dtype):
     # drawn and scaled in float32, rounded once: no fusion can change it
     x = jax.random.normal(key, shape, jnp.float32) * jnp.float32(scale)
     return x.astype(dtype)
-
-
-def layer_weights(c: dict, key, dtype):
-    """One layer's weights in the program's layout (``_init_layer``)."""
-    import jax
-    import jax.numpy as jnp
-    D, F = c["hidden_size"], c["intermediate_size"]
-    qd = c["num_attention_heads"] * c["head_dim"]
-    kvd = c["num_key_value_heads"] * c["head_dim"]
-    ks = jax.random.split(key, 12)
-    attn = {"wq": _normal(jax, jnp, ks[0], (D, qd), D ** -0.5, dtype),
-            "wk": _normal(jax, jnp, ks[1], (D, kvd), D ** -0.5, dtype),
-            "wv": _normal(jax, jnp, ks[2], (D, kvd), D ** -0.5, dtype),
-            "wo": _normal(jax, jnp, ks[3], (qd, D), qd ** -0.5, dtype)}
-    if c["attention_bias"]:
-        attn["bq"] = _normal(jax, jnp, ks[4], (qd,), 0.1, dtype)
-        attn["bk"] = _normal(jax, jnp, ks[5], (kvd,), 0.1, dtype)
-        attn["bv"] = _normal(jax, jnp, ks[6], (kvd,), 0.1, dtype)
-    return {"ln_attn": {"scale": _normal(jax, jnp, ks[7], (D,), 0.1, dtype)},
-            "attn": attn,
-            "ln_mlp": {"scale": _normal(jax, jnp, ks[8], (D,), 0.1, dtype)},
-            "mlp": {"w_gate": _normal(jax, jnp, ks[9], (D, F), D ** -0.5,
-                                      dtype),
-                    "w_up": _normal(jax, jnp, ks[10], (D, F), D ** -0.5,
-                                    dtype),
-                    "w_down": _normal(jax, jnp, ks[11], (F, D), F ** -0.5,
-                                      dtype)}}
 
 
 def embed_weights(c: dict, key, dtype):
@@ -121,7 +97,8 @@ def build_params(c: dict, key, dtype):
     """All weights for ``key`` (traceable: call it inside ``jax.jit``)."""
     import jax
     import jax.numpy as jnp
-    layers = jax.vmap(lambda l: layer_weights(c, layer_key(key, l), dtype))(
+    a = arch(c)
+    layers = jax.vmap(lambda l: a.layer_weights(c, layer_key(key, l), dtype))(
         jnp.arange(c["num_hidden_layers"]))
     p = {"embed": embed_weights(c, key, dtype), "layers": layers,
          "ln_final": {"scale": final_norm(c, key, dtype)}}

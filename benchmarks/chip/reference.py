@@ -1,19 +1,19 @@
-"""Plain float32 reference of a dense GQA decoder (Qwen2 / InternLM2 kind).
+"""Plain float32 reference of a served decoder, a whole sequence at a time.
 
-Written from the published architecture, not from the program: pre-norm
-RMSNorm blocks, rotary embedding on the two halves of each head (the Hugging
-Face ``rotate_half`` layout), causal grouped-query softmax attention, and a
-SiLU-gated MLP. Weights are redrawn from the seed one layer at a time
-(``model.layer_weights``), upcast to float32, so the reference never holds
-more than one layer and imports nothing of the program. Norm gains are
-stored as offsets from 1: a layer's gain vector is ``1 + scale``.
+Written from the published architecture, not from the program: the
+embedding, the configuration's decoder layers (``layer`` of its
+architecture module, ``model.arch``), a final RMSNorm with its gain stored
+as an offset from 1, and the unembedding. Weights are redrawn from the
+seed one layer at a time (``layer_weights``), upcast to float32, so the
+reference never holds more than one layer and imports nothing of the
+program.
 
 ``logits_at`` runs whole sequences and returns the logits at chosen
 positions; the embedding and the unembedding stay in bf16 and are upcast
 a row or a block of the vocabulary at a time. With ``quant="fp8"`` every
-matrix product takes its inputs rounded to float8 e4m3 (weights per output column, activations per row,
-each scaled to the format's range): the control that stands in for a
-lower-precision program.
+product with a weight takes its inputs rounded to float8 e4m3 (weights
+per output column, activations per row, each scaled to the format's
+range): the control that stands in for a lower-precision program.
 """
 from __future__ import annotations
 
@@ -58,7 +58,7 @@ def _rms(jnp, x, scale, eps):
 
 
 def _rope(jnp, x, pos, theta):
-    """x (S, H, Dh); pos (S,)."""
+    """x (..., S, H, Dh); pos (S,)."""
     half = x.shape[-1] // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos[:, None].astype(jnp.float32) * inv[None, :]
@@ -68,41 +68,19 @@ def _rope(jnp, x, pos, theta):
 
 
 def layer_fn(c: dict, quant: Optional[str]):
-    """(weights f32, x (S, D), n_valid) -> x after one decoder layer."""
-    import jax
+    """(weights f32, x (S, D)) -> x after one decoder layer: the
+    architecture's ``layer`` over one sequence, its products with weights
+    through ``_mm`` and attention's at full precision."""
     import jax.numpy as jnp
-    H, Hkv, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["head_dim"])
-    eps, theta = float(c["rms_norm_eps"]), float(c["rope_theta"])
+    arch = bench_model.arch(c)
 
-    def apply(w, x):
-        S = x.shape[0]
-        pos = jnp.arange(S)
-        a = w["attn"]
-        h = _rms(jnp, x, w["ln_attn"]["scale"], eps)
-        q = _mm(jnp, h, a["wq"], quant)
-        k = _mm(jnp, h, a["wk"], quant)
-        v = _mm(jnp, h, a["wv"], quant)
-        if c["attention_bias"]:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        q = _rope(jnp, q.reshape(S, H, Dh), pos, theta)
-        k = _rope(jnp, k.reshape(S, Hkv, Dh), pos, theta)
-        v = v.reshape(S, Hkv, Dh)
-        qg = q.reshape(S, Hkv, H // Hkv, Dh)
-        s = jnp.einsum("qhgd,khd->hgqk", qg, k,
-                       precision="highest") * Dh ** -0.5
-        causal = pos[None, :] <= pos[:, None]
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("hgqk,khd->qhgd", p, v, precision="highest")
-        x = x + _mm(jnp, o.reshape(S, H * Dh), a["wo"], quant)
-        h = _rms(jnp, x, w["ln_mlp"]["scale"], eps)
-        m = w["mlp"]
-        g = jax.nn.silu(_mm(jnp, h, m["w_gate"], quant))
-        u = _mm(jnp, h, m["w_up"], quant)
-        return x + _mm(jnp, g * u, m["w_down"], quant)
+    def mm(x, w):
+        return _mm(jnp, x, w, quant)
 
-    return apply
+    def attn_mm(a, b):
+        return jnp.matmul(a, b, precision="highest")
+
+    return lambda w, x: arch.layer(c, w, x[None], mm, attn_mm)[0]
 
 
 def layer_getter(c: dict):
@@ -125,8 +103,8 @@ def _programs(c: dict) -> dict:
             "embed": jax.jit(lambda k: bench_model.embed_weights(c, k, bf16)),
             "layer": jax.jit(lambda k, l: jax.tree.map(
                 lambda a: a.astype(f32),
-                bench_model.layer_weights(c, bench_model.layer_key(k, l),
-                                          bf16))),
+                bench_model.arch(c).layer_weights(
+                    c, bench_model.layer_key(k, l), bf16))),
             "head": jax.jit(lambda k: bench_model.head_weights(c, k, bf16)),
             "gain": jax.jit(lambda k: bench_model.final_norm(c, k, bf16)
                             .astype(f32)),

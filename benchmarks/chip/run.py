@@ -111,13 +111,18 @@ def setup_jax(chips: int):
 
 
 def device_info(devs, chips: int) -> dict:
+    """The cell's devices; peaks on the fullest chip: ``peak_bytes_in_use``
+    and, apart, ``peak_bytes_reserved`` (the programs' temporaries, which
+    the runtime reserves outside what is in use)."""
     used = devs[:chips]
-    peak = 0
+    peak = reserved = 0
     for d in used:
         st = d.memory_stats() or {}
         peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
+        reserved = max(reserved, int(st.get("peak_bytes_reserved", 0)))
     return {"platform": used[0].platform, "kind": used[0].device_kind,
-            "count": len(used), "memory_peak_bytes": peak}
+            "count": len(used), "memory_peak_bytes": peak,
+            "memory_reserved_peak_bytes": reserved}
 
 
 def reduce_trace(w, tr_dir: Path):

@@ -118,8 +118,8 @@ class Trainer:
         self.precision = t["matmul_precision"]
         self.mesh = make_test_mesh(1)
         self.opt = opt_config(c)
-        fn = build_train_step(bench_model.program_config(c), self.mesh,
-                              opt=self.opt, remat=t["remat"])
+        fn = build_train_step(bench_model.arch(c).program_config(c),
+                              self.mesh, opt=self.opt, remat=t["remat"])
         if wrap is not None:
             fn = wrap(fn)
 
@@ -344,14 +344,29 @@ def measure(args, cell, c, job, devs, compile_log, rt, bench,
     ctx = {"setup_s": setup_s, "peaks": pk}
     breakdown = None
     if tr_dir is not None:
+        import scopes
         import trace_reduce
         path = trace_reduce.find(str(tr_dir))
         if path is not None:
-            dev_t, breakdown, mods = reduce_trace(trace_reduce.load(path))
+            trace = trace_reduce.load(path)
+            dev_t, breakdown, mods = reduce_trace(trace)
             ctx["device_time"], ctx["step_modules"] = dev_t, mods
             if dev_t:
                 device["busy_s"] = dev_t["busy_s"]
                 device["window_s"] = dev_t["window_s"]
+            n0 = compile_log.programs
+            split = scopes.by_scope(
+                trace, scopes.op_names_from_hlo(scopes.step_hlo(tr)))
+            ctx["scope_split"] = split
+            if split and mods:
+                ms = dict(scopes.group_ms(split), unscoped=1e3 * sum(
+                    v for (sc, _), v in split.items()
+                    if sc == scopes.UNSCOPED),
+                    step_ops=1e3 * sum(split.values()),
+                    step_programs=1e3 * mods["seconds"] / mods["count"])
+                log("device ms per traced step, by scope: " + " ".join(
+                    f"{k}={v}" for k, v in ms.items())
+                    + f"; compiles for the HLO: {compile_log.programs - n0}")
         shutil.rmtree(tr_dir, ignore_errors=True)
     metrics = {}
     for m in rt.cell_metrics(bench, cell, bool(args.trace)):

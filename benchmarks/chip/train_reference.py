@@ -1,26 +1,26 @@
-"""Plain float32 reference of a training step of the dense GQA decoder.
+"""Plain float32 reference of a training step.
 
-The architecture is ``reference.py``'s (pre-norm RMSNorm blocks with gains
-stored as offsets from 1, rotary embedding on the two halves of each head,
-causal grouped-query softmax attention, a SiLU-gated MLP, tied or untied
-unembedding), here over whole batches: the mean next-token cross-entropy
-over every row and position, its gradient by ``jax.value_and_grad``, and
-AdamW as the configuration's ``train.optimizer`` states it: the gradient
-clipped to a global norm (``clip / (norm + 1e-6)``, at most 1), moments
-with bias correction, weight decay decoupled and scaled by the learning
-rate, a linear warm-up to ``lr`` over ``warmup_steps`` (``lr * (k + 1) /
-warmup_steps`` at step ``k``) and then a cosine decay to 0 at
-``total_steps``. It imports nothing of the program: weights are redrawn
-from the seed (``model.build_params``) and batches from the job
-(``train.batch``).
+The architecture is ``reference.py``'s (the configuration's decoder layers,
+``layer`` of its architecture module, between the embedding and a final
+RMSNorm and the tied or untied unembedding), here over whole batches: the
+mean next-token cross-entropy over every row and position, its gradient by
+``jax.value_and_grad``, and AdamW as the configuration's
+``train.optimizer`` states it: the gradient clipped to a global norm
+(``clip / (norm + 1e-6)``, at most 1), moments with bias correction,
+weight decay decoupled and scaled by the learning rate, a linear warm-up
+to ``lr`` over ``warmup_steps`` (``lr * (k + 1) / warmup_steps`` at step
+``k``) and then a cosine decay to 0 at ``total_steps``. It imports nothing
+of the program: weights are redrawn from the seed (``model.build_params``)
+and batches from the job (``train.batch``).
 
-Every matrix product runs at ``precision="highest"``. With
-``quant="high"`` every product, forward and backward, is the three-pass
-bf16 product (the high part of each factor times the other's high part,
-plus the two cross terms): the control, float32 one step below
-``highest``. Each layer is recomputed in the backward pass
-(``jax.checkpoint``) and the cross-entropy is taken one row at a time, so
-that the reference fits beside its float32 weights and moments.
+Every matrix product, attention's included, runs at
+``precision="highest"``. With ``quant="high"`` every product, forward and
+backward, is the three-pass bf16 product (the high part of each factor
+times the other's high part, plus the two cross terms): the control,
+float32 one step below ``highest``. Each layer is recomputed in the
+backward pass (``jax.checkpoint``) and the cross-entropy is taken one row
+at a time, so that the reference fits beside its float32 weights and
+moments.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from typing import Dict, Optional
 import numpy as np
 
 import model as bench_model
-from reference import _rms, _rope
+from reference import _rms
 
 QUANTS = (None, "high")
 
@@ -86,38 +86,11 @@ def loss_fn(c: dict, quant: Optional[str]):
     import jax
     import jax.numpy as jnp
     mm = matmul(quant)
-    H, Hkv, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                  c["head_dim"])
-    G = H // Hkv
-    eps, theta = float(c["rms_norm_eps"]), float(c["rope_theta"])
+    arch = bench_model.arch(c)
+    eps = float(c["rms_norm_eps"])
 
     def layer(x, w):
-        B, S, _ = x.shape
-        pos = jnp.arange(S)
-        a = w["attn"]
-        h = _rms(jnp, x, w["ln_attn"]["scale"], eps)
-        q, k, v = mm(h, a["wq"]), mm(h, a["wk"]), mm(h, a["wv"])
-        if c["attention_bias"]:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        q = _rope(jnp, q.reshape(B, S, H, Dh), pos, theta)
-        k = _rope(jnp, k.reshape(B, S, Hkv, Dh), pos, theta)
-        v = v.reshape(B, S, Hkv, Dh)
-        # (B, Hkv, G, S, Dh): each query head with its key-value group
-        q5 = q.reshape(B, S, Hkv, G, Dh).transpose(0, 2, 3, 1, 4)
-        k5 = jnp.broadcast_to(k.transpose(0, 2, 1, 3)[:, :, None],
-                              (B, Hkv, G, S, Dh))
-        v5 = jnp.broadcast_to(v.transpose(0, 2, 1, 3)[:, :, None],
-                              (B, Hkv, G, S, Dh))
-        s = mm(q5, jnp.swapaxes(k5, -1, -2)) * Dh ** -0.5
-        causal = pos[None, :] <= pos[:, None]
-        s = jnp.where(causal, s, -jnp.inf)
-        o = mm(jax.nn.softmax(s, axis=-1), v5)
-        o = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H * Dh)
-        x = x + mm(o, a["wo"])
-        h = _rms(jnp, x, w["ln_mlp"]["scale"], eps)
-        m = w["mlp"]
-        g = jax.nn.silu(mm(h, m["w_gate"]))
-        return x + mm(g * mm(h, m["w_up"]), m["w_down"]), None
+        return arch.layer(c, w, x, mm, mm), None
 
     def loss(params, tokens, targets):
         x = params["embed"][tokens]

@@ -7,7 +7,9 @@ its files by name; the trace reduction and the operation/byte counters give
 hand-counted answers; the reference agrees with the program's own float32
 forward pass; the harness core serves a tiny configuration end to end and
 its check passes, and the fp8 control and a step broken underneath fail it;
-the measured path refuses a host without a TPU.
+the measured path refuses a host without a TPU. Every configuration names
+an architecture module under ``arch/``; the weights and the served
+reference's logits match values pinned before the architecture moved there.
 """
 import json
 import os
@@ -126,7 +128,7 @@ def test_cell_resolves_its_files(cell):
     cfg = next(x for x in BENCH["configs"] if x["name"] == w["config"])
     assert cfg["file"] == f"benchmarks/chip/configs/{w['config']}.json"
     assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-    assert bench_model.program_config(c).n_layers == c["num_hidden_layers"]
+    _resolves_its_arch(c)
     if spec.get("kind") == "train":
         assert train.opt_config(c).lr > 0
         assert spec["batch"] >= 2 and spec["seq_len"] > 0
@@ -141,6 +143,51 @@ def test_cell_resolves_its_files(cell):
             assert callable(run.reader(m["name"]))
     assert "setup_s" in [m["name"] for m in run.cell_metrics(BENCH, w,
                                                               False)]
+
+
+ARCH_FUNCTIONS = ("program_config", "layer_weights", "layer_matmul_params",
+                  "layer")
+
+
+def _resolves_its_arch(c):
+    a = bench_model.arch(c)
+    assert a.__name__ == f"arch.{c['reference']}"
+    assert (HERE / "arch" / f"{c['reference']}.py").is_file()
+    for fn in ARCH_FUNCTIONS:
+        assert callable(getattr(a, fn)), fn
+    assert a.program_config(c).n_layers == c["num_hidden_layers"]
+    assert a.layer_matmul_params(c) > 0
+
+
+@pytest.mark.parametrize("name", sorted(
+    p.stem for p in (HERE / "configs").glob("*.json")))
+def test_every_config_resolves_its_arch(name):
+    _resolves_its_arch(bench_model.load(name))
+
+
+def test_unknown_arch_fails_with_the_path_it_expected():
+    c = dict(TINY, name="nowhere", reference="no_such_arch")
+    expected = str(HERE / "arch" / "no_such_arch.py")
+    with pytest.raises(ValueError, match="no_such_arch") as e:
+        bench_model.arch(c)
+    assert expected in str(e.value)
+
+
+def test_device_info_reports_both_peaks_of_the_fullest_chips():
+    class Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+        def __init__(self, in_use, reserved):
+            self.st = {"peak_bytes_in_use": in_use,
+                       "peak_bytes_reserved": reserved}
+
+        def memory_stats(self):
+            return self.st
+    devs = [Dev(5, 40), Dev(7, 30), Dev(99, 99)]
+    info = run.device_info(devs, 2)
+    assert info == {"platform": "tpu", "kind": "TPU v5 lite", "count": 2,
+                    "memory_peak_bytes": 7,
+                    "memory_reserved_peak_bytes": 40}
 
 
 def test_peaks_table():
@@ -163,7 +210,7 @@ def test_counters_match_hand_counts():
     assert f == L * 4 * H * Dh * 21
     assert b == L * 2 * (2 * Hkv * Dh * 8 + 2 * H * Dh * 3)
     per_layer = 64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128
-    assert counts.layer_matmul_params(TINY) == per_layer
+    assert bench_model.arch(TINY).layer_matmul_params(TINY) == per_layer
     flops = counts.step_flops(TINY, [(5, 3)], [10])
     assert flops == (2 * L * per_layer * 4 + 2 * 64 * TINY["vocab_size"] * 2
                      + L * 4 * H * Dh * (21 + 10))
@@ -234,7 +281,7 @@ def test_reference_matches_program_forward_in_f32():
     seed = 99
     p = jax.tree.map(lambda a: a.astype(jnp.float32),
                      bench_model.make_params(TINY, seed))
-    cfg = bench_model.program_config(TINY)
+    cfg = bench_model.arch(TINY).program_config(TINY)
     toks = np.random.default_rng(0).integers(2, 256, 70).astype(np.int32)
     old = jax.config.jax_default_matmul_precision
     jax.config.update("jax_default_matmul_precision", "highest")
@@ -246,6 +293,81 @@ def test_reference_matches_program_forward_in_f32():
     ref = reference.logits_at(TINY, seed, [toks], [pos])[None][0]
     assert np.max(np.abs(prog[pos] - ref)) < 2e-4
     assert reference.widest_gap(ref, ref.argmax(-1)) == 0.0
+
+
+# Pinned on the tree before the architecture moved into ``arch/``
+# (``dense_gqa``): the weights must come out bit for bit, the served
+# reference's logits within 1e-6 of each row's largest.
+WEIGHT_DIGESTS = {
+    ("float32", True):
+        "eb7ae37d496ad11f0eb6ca49755ca5ffd477c55bf56a6d9479f581b7404d2ff0",
+    ("float32", False):
+        "c96c0355cd013077eaf50f7716cca935c6aecb18c330d12e53437e2a42dcfb99",
+    ("bfloat16", True):
+        "d0268ec568ad30f3497beab2f6235904e11024559b212ee64dc161be5aeca717",
+    ("bfloat16", False):
+        "fee233a1f875412a3bbe213518b972169cadaeeb065c0e92f443556c5af27b08",
+}
+LOGIT_COLUMNS = [0, 1, 7, 100, 500, 777, 1023]
+# (values at LOGIT_COLUMNS, largest magnitude, norm) at positions 0, 31, 69
+PINNED_LOGITS = {
+    None: [
+        ([-1.4404207468032837, -0.03387024998664856, -0.31965988874435425,
+          0.004857867956161499, -0.1966381072998047, 0.5339019894599915,
+          -0.10468155145645142], 2.9799156188964844, 31.36590003967285),
+        ([-0.8426394462585449, 1.2275207042694092, 0.2518796920776367,
+          0.36710724234580994, -2.3695573806762695, 1.4645558595657349,
+          1.2374111413955688], 3.1048357486724854, 33.04469680786133),
+        ([-0.11119922995567322, -0.5495830178260803, 1.994350552558899,
+          2.0797977447509766, -1.6015658378601074, 0.8254010677337646,
+          0.1128140315413475], 3.35341739654541, 33.160640716552734)],
+    "fp8": [
+        ([-1.4081964492797852, -0.03934553265571594, -0.3442038595676422,
+          -0.06531476974487305, -0.2839580774307251, 0.5901947021484375,
+          -0.11226196587085724], 2.9604601860046387, 31.300485610961914),
+        ([-0.9019074440002441, 1.2323389053344727, 0.3663560152053833,
+          0.3933665156364441, -2.2445452213287354, 1.4740355014801025,
+          1.21414315700531], 3.0731983184814453, 32.791221618652344),
+        ([-0.003672271966934204, -0.572869062423706, 2.193364143371582,
+          2.1878859996795654, -1.4293105602264404, 0.7815207242965698,
+          0.1338692605495453], 3.2449893951416016, 33.75417709350586)],
+}
+
+
+def tree_digest(tree):
+    """sha256 over every leaf's path, dtype, shape and bytes."""
+    import hashlib
+    import jax
+    h = hashlib.sha256()
+    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(x)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("dtype,tied", sorted(WEIGHT_DIGESTS))
+def test_weights_match_their_pinned_digest(dtype, tied):
+    import jax.numpy as jnp
+    if tied:
+        c, seed = TINY, 2**33 + 5
+    else:
+        c = dict(TINY, tie_word_embeddings=False, attention_bias=False)
+        seed = 7
+    p = bench_model.make_params(c, seed, jnp.dtype(dtype))
+    assert tree_digest(p) == WEIGHT_DIGESTS[dtype, tied]
+
+
+def test_served_reference_logits_match_their_pinned_values():
+    toks = np.random.default_rng(0).integers(2, 256, 70).astype(np.int32)
+    out = reference.logits_at(TINY, 99, [toks], [np.array([0, 31, 69])],
+                              (None, "fp8"))
+    for q, rows in PINNED_LOGITS.items():
+        for got, (vals, top, norm) in zip(out[q][0], rows):
+            assert np.max(np.abs(got[LOGIT_COLUMNS] - vals)) <= 1e-6 * top
+            assert abs(np.max(np.abs(got)) - top) <= 1e-6 * top
+            assert abs(np.linalg.norm(got) - norm) <= 1e-6 * norm
 
 
 # --- the harness core at a tiny size ---------------------------------------

@@ -7,7 +7,10 @@ count and the trace reduction give hand-counted answers; at a tiny size
 the program's train step agrees with the float32 reference, its three-pass
 control fails the check, and a run driven through ``train.measure`` with
 the step broken underneath (state unchanged, half of the batch left out)
-reads ``correct`` false.
+reads ``correct`` false. The reference's readings match a digest pinned
+before the architecture moved into ``arch/``; an architecture module in a
+new file elsewhere is enough for a correct run; a traced run reads the
+layer-scope split into its four metrics.
 """
 import argparse
 import sys
@@ -23,6 +26,7 @@ sys.path[:0] = [str(HERE), str(ROOT / "src")]
 import calibrate_train  # noqa: E402
 import counts  # noqa: E402
 import harness  # noqa: E402
+import model as bench_model  # noqa: E402
 import peaks  # noqa: E402
 import run  # noqa: E402
 import trace_reduce  # noqa: E402
@@ -116,6 +120,23 @@ def references():
             for seed in (1, 2**33 + 7)}
 
 
+# pinned on the tree before the architecture moved into ``arch/``
+READINGS_DIGEST = (
+    "b7f849a042fa612f2b470cc0640e9b088b3e5225ae378d6c95fd7e13e9cdb4fd")
+
+
+def test_reference_readings_match_their_pinned_digest(references):
+    """The reference that decides ``correct`` does the same arithmetic, bit
+    for bit: losses, gradient and change norms of seed 1."""
+    import hashlib
+    r = references[1]
+    h = hashlib.sha256()
+    for a in (np.asarray(r["loss"], np.float64), r["grad"], r["change"]):
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == READINGS_DIGEST
+
+
 @pytest.mark.parametrize("seed", [1, 2**33 + 7])
 def test_program_passes_and_the_control_fails(seed, references):
     """The program's first steps agree with the float32 reference; the
@@ -146,12 +167,12 @@ BENCH = {"end_to_end": [{"name": "train_tokens_per_s", "unit": "tokens/s"},
          "per_layer": []}
 
 
-def _measure(seed, wrap=None):
+def _measure(seed, wrap=None, c=TINY, trace=0, bench=BENCH):
     import jax
-    args = argparse.Namespace(seed=seed, seconds=1.5, trace=0)
-    cell = {"name": "tiny.tiny-train", "chips": 1}
-    return train.measure(args, cell, TINY, JOB, [_Device()],
-                         harness.CompileLog(jax), run, BENCH, wrap=wrap)
+    args = argparse.Namespace(seed=seed, seconds=1.5, trace=trace)
+    cell = {"name": f"{c['name']}.tiny-train", "chips": 1}
+    return train.measure(args, cell, c, JOB, [_Device()],
+                         harness.CompileLog(jax), run, bench, wrap=wrap)
 
 
 def test_train_run_is_correct_and_reports_its_metrics():
@@ -174,3 +195,81 @@ def _state_unchanged(fn):
 def test_train_run_with_a_broken_step_is_not_correct(fault):
     result = _measure(3, wrap=fault)
     assert result["correct"] is False, result["checks"]
+
+
+def test_a_new_architecture_needs_only_new_files(tmp_path, monkeypatch):
+    """An architecture module under a new name, outside the benchmark's
+    files, and a configuration that names it: a whole run is correct."""
+    (tmp_path / "arch").mkdir()
+    (tmp_path / "arch" / "dense_gqa_elsewhere.py").write_text(
+        "from arch.dense_gqa import (layer, layer_matmul_params,\n"
+        "                            layer_weights, program_config)\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    c = dict(TINY, name="tiny-elsewhere", reference="dense_gqa_elsewhere")
+    assert Path(bench_model.arch(c).__file__).parent == tmp_path / "arch"
+    result = _measure(3, c=c)      # the seed of the dense run above
+    assert result["correct"] is True, result["checks"]
+
+
+SCOPE_METRICS = ("train_vocab_ms", "train_attn_ms", "train_mlp_ms",
+                 "train_adamw_ms")
+
+
+def test_traced_run_reads_the_scope_split_of_the_compiled_step(
+        tmp_path, monkeypatch):
+    """With ``--trace 1`` the run splits the traced steps by the scopes
+    named in the compiled step's HLO, compiling nothing for it, and the
+    four scope metrics read that split. (A CPU trace has no device ops, so
+    the split itself is stood in for.)"""
+    import jax
+    import scopes
+    split = {("embed", "forward"): 1e-3, ("head", "backward"): 2e-3,
+             ("attn", "forward"): 3e-3, ("mlp", "backward"): 4e-3,
+             ("adamw", "forward"): 5e-3, ("unscoped", "forward"): 6e-3}
+    seen = {}
+    log = harness.CompileLog(jax)
+    step_hlo = scopes.step_hlo
+
+    def hlo(tr):
+        n0 = log.programs
+        text = step_hlo(tr)
+        seen["compiles"] = log.programs - n0
+        return text
+
+    def by_scope(trace, op_names):
+        seen["op_names"] = op_names
+        return split
+    monkeypatch.setattr(scopes, "step_hlo", hlo)
+    monkeypatch.setattr(scopes, "by_scope", by_scope)
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path)
+    bench = {"end_to_end": [], "per_layer": [
+        {"name": n, "unit": "ms"} for n in SCOPE_METRICS]}
+    result = _measure(6, trace=1, bench=bench)
+    assert result["correct"] is True, result["checks"]
+    assert seen["compiles"] == 0
+    found = {scopes.scope_of(v)[0] for v in seen["op_names"].values()}
+    assert set(scopes.LAYERS) <= found
+    assert {k: v["value"] for k, v in result["metrics"].items()} == {
+        "train_vocab_ms": pytest.approx(3.0),
+        "train_attn_ms": pytest.approx(3.0),
+        "train_mlp_ms": pytest.approx(4.0),
+        "train_adamw_ms": pytest.approx(5.0)}
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_readers(name):
+    import importlib
+    import scopes
+    read = importlib.import_module(f"metrics.{name}").read
+    split = {("embed", "forward"): 0.001, ("head", "backward"): 0.010,
+             ("loss", "forward"): 0.002, ("attn", "backward"): 0.004,
+             ("mlp", "forward"): 0.020, ("mlp", "backward"): 0.030,
+             ("adamw", "forward"): 0.005, ("unscoped", "forward"): 0.5}
+    want = {"train_vocab_ms": 13.0, "train_attn_ms": 4.0,
+            "train_mlp_ms": 50.0, "train_adamw_ms": 5.0}[name]
+    assert read(None, {"scope_split": split}) == pytest.approx(want)
+    assert read(None, {"scope_split": split}) == pytest.approx(
+        scopes.group_ms(split)[name])
+    assert read(None, {}) is None
+    assert read(None, {"scope_split": None}) is None
+    assert read(None, {"scope_split": {("unscoped", "forward"): 0.5}}) is None
