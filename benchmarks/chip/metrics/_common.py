@@ -64,10 +64,19 @@ def roofline(flops, bytes_, seconds, pk):
 
 def scope_ms(ctx, group):
     """Device milliseconds per traced train step in ``scopes.GROUPS[group]``,
-    both phases (``scopes.group_ms`` of the window's split); ``None``
-    without a split or where none of the group's scopes ran."""
+    every part and phase (``scopes.group_ms`` of the window's split);
+    ``None`` without a split or where none of the group's scopes ran."""
     import scopes
     split = ctx.get("scope_split")
-    if not split or not any(sc in scopes.GROUPS[group] for sc, _ in split):
+    if not split or not any(k[0] in scopes.GROUPS[group] for k in split):
         return None
     return scopes.group_ms(split)[group]
+
+
+def split_ms(ctx, scope=None, part=None, phase=None):
+    """Device milliseconds per traced train step with that scope, part and
+    phase (``None``: any; ``scopes.layer_ms`` of the window's split);
+    ``None`` without a split or where no op had them."""
+    import scopes
+    split = ctx.get("scope_split")
+    return scopes.layer_ms(split, scope, part, phase) if split else None

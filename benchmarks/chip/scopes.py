@@ -1,16 +1,18 @@
 #!/usr/bin/env python3
 """Device time of the train step by the program's layer scopes.
 
-The program names its device work with ``jax.named_scope``
-(``repro.obs.scopes``): ``embed``, ``attn``, ``mlp``, ``head``, ``loss`` and
-``adamw``. Each name lands in the ``op_name`` metadata of the HLO
+The program names its device work with ``jax.named_scope``; the names are
+its own (``repro.obs.scopes``): the layers ``LAYERS`` (``embed``, ``attn``,
+``mlp``, ``head``, ``loss``, ``adamw``) and, nested inside a layer, the
+parts ``PARTS`` that an architecture names (none where the program
+declares none). Each name lands in the ``op_name`` metadata of the HLO
 instructions traced under it. A TPU v5e trace's op events carry no
 ``op_name``, only the instruction's name, so the names are read from the
 compiled step's optimized HLO text (``op_names_from_hlo``). ``by_scope``
 sums the device seconds of the ``XLA Ops`` events that run inside a
-``train_step`` program event, by scope and phase, per step. Run as a script
-on the chip, it traces a few steps of a training cell and prints that
-split, one JSON object:
+``train_step`` program event, by scope, part and phase, per step. Run as
+a script on the chip, it traces a few steps of a training cell and prints
+that split, one JSON object:
 
     python3 benchmarks/chip/scopes.py --workload <cell> --seed <n> \
         [--seconds 4]
@@ -20,13 +22,21 @@ Not part of a benchmark run.
 from __future__ import annotations
 
 import re
+from functools import lru_cache
 from typing import Dict, Optional, Tuple
 
-LAYERS = ("embed", "attn", "mlp", "head", "loss", "adamw")
+if __name__ == "__main__":
+    import run  # noqa: F401  (puts the program's src/ on sys.path)
+
+from repro.obs import scopes as program_scopes  # noqa: E402
+
+LAYERS = program_scopes.LAYERS
 UNSCOPED = "unscoped"
 STEP_MODULE = "train_step"
+PHASES = ("forward", "backward", "recompute")
 
-# per-layer readings of the train step: device ms per step, both phases
+# per-layer readings of the train step: device ms per step, every part and
+# phase; a scope outside these is read with ``layer_ms``
 GROUPS = {
     "train_vocab_ms": ("embed", "head", "loss"),
     "train_attn_ms": ("attn",),
@@ -34,18 +44,39 @@ GROUPS = {
     "train_adamw_ms": ("adamw",),
 }
 
-_NAME = re.compile(r"(?<![A-Za-z0-9_])(%s)(?![A-Za-z0-9_])" % "|".join(LAYERS))
+Key = Tuple[str, str, str]
+
 _HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*\bop_name="([^"]*)"',
                      re.M)
 
 
-def scope_of(op_name: str) -> Tuple[str, str]:
-    """(scope, phase) of an ``op_name``: the last of ``LAYERS`` in it as a
-    whole word (``.../checkpoint/mlp/add_any``, ``jvp(mlp)``), else
-    ``unscoped``; ``backward`` where it holds ``transpose(``."""
-    names = _NAME.findall(op_name or "")
-    phase = "backward" if "transpose(" in (op_name or "") else "forward"
-    return (names[-1] if names else UNSCOPED), phase
+def parts() -> Tuple[str, ...]:
+    """The program's sub-scopes that nest inside a layer scope."""
+    return tuple(getattr(program_scopes, "PARTS", ()))
+
+
+@lru_cache(maxsize=None)
+def _words(names: Tuple[str, ...]):
+    return re.compile(r"(?<![A-Za-z0-9_])(%s)(?![A-Za-z0-9_])"
+                      % "|".join(map(re.escape, names)))
+
+
+def scope_of(op_name: str) -> Key:
+    """(scope, part, phase) of an ``op_name``. The scope is the last of
+    ``LAYERS`` in it as a whole word (``.../checkpoint/mlp/add_any``,
+    ``jvp(mlp)``), else ``unscoped``; the part the last of ``parts()``
+    after that scope, else ``""``; the phase ``recompute`` where it holds
+    ``rematted_computation``, ``backward`` where it holds ``transpose(``,
+    else ``forward``."""
+    s = op_name or ""
+    found = list(_words(LAYERS).finditer(s))
+    part = ""
+    if found and parts():
+        after = _words(parts()).findall(s, found[-1].end())
+        part = after[-1] if after else ""
+    phase = ("recompute" if "rematted_computation" in s
+             else "backward" if "transpose(" in s else "forward")
+    return (found[-1].group(1) if found else UNSCOPED), part, phase
 
 
 def op_names_from_hlo(text: str) -> Dict[str, str]:
@@ -54,13 +85,13 @@ def op_names_from_hlo(text: str) -> Dict[str, str]:
 
 
 def by_scope(tr, op_names: Dict[str, str], module: str = STEP_MODULE
-             ) -> Optional[Dict[Tuple[str, str], float]]:
-    """Device seconds per step by (scope, phase) of the non-container op
-    events that start inside a ``module`` program event of their plane;
-    ``None`` without such an event."""
+             ) -> Optional[Dict[Key, float]]:
+    """Device seconds per step by (scope, part, phase) of the
+    non-container op events that start inside a ``module`` program event
+    of their plane; ``None`` without such an event."""
     import bisect
     import trace_reduce
-    out: Dict[Tuple[str, str], float] = {}
+    out: Dict[Key, float] = {}
     steps = 0
     for plane, mods in tr.modules.items():
         mods = sorted((m for m in mods if module in m.name),
@@ -80,10 +111,21 @@ def by_scope(tr, op_names: Dict[str, str], module: str = STEP_MODULE
     return {k: s / steps for k, s in out.items()}
 
 
-def group_ms(split: Dict[Tuple[str, str], float]) -> Dict[str, float]:
+def group_ms(split: Dict[Key, float]) -> Dict[str, float]:
     """``GROUPS``' device milliseconds per step from ``by_scope``."""
-    return {g: 1e3 * sum(s for (sc, _), s in split.items() if sc in names)
+    return {g: 1e3 * sum(s for (sc, _, _), s in split.items() if sc in names)
             for g, names in GROUPS.items()}
+
+
+def layer_ms(split: Dict[Key, float], scope: Optional[str] = None,
+             part: Optional[str] = None, phase: Optional[str] = None
+             ) -> Optional[float]:
+    """Device milliseconds per step of ``by_scope``'s entries with that
+    scope, part and phase (``None``: any); ``None`` where no entry has
+    them."""
+    got = [s for k, s in split.items()
+           if all(w is None or w == v for w, v in zip((scope, part, phase), k))]
+    return 1e3 * sum(got) if got else None
 
 
 # --- on the chip -----------------------------------------------------------
@@ -107,7 +149,7 @@ def main(argv=None) -> int:
     import sys
     import time
 
-    import run  # sets up sys.path
+    import run
 
     import harness
     import model as bench_model
@@ -157,9 +199,10 @@ def main(argv=None) -> int:
     print(json.dumps({
         "device": devs[0].device_kind, "steps": steps,
         "step_op_ms": 1e3 * total,
-        "unscoped_share": sum(s for (sc, _), s in split.items()
-                              if sc == UNSCOPED) / total,
-        "ms": {f"{sc}.{ph}": 1e3 * s for (sc, ph), s in sorted(split.items())},
+        "unscoped_share": (layer_ms(split, UNSCOPED) or 0.0) / (1e3 * total),
+        "ms": {".".join(filter(None, k)): 1e3 * s
+               for k, s in sorted(split.items())},
+        "phases": {ph: layer_ms(split, phase=ph) for ph in PHASES},
         "groups": group_ms(split),
     }))
     return 0

@@ -148,8 +148,9 @@ class Trainer:
     def free(self) -> None:
         self.params = self.opt_state = None
 
-    def dispatch(self):
-        """Feed and send step ``k``; returns its loss (not waited for)."""
+    def dispatch(self) -> dict:
+        """Feed and send step ``k``; returns the step's outputs, its loss
+        among them (not waited for)."""
         import jax.numpy as jnp
         toks, tgts = batch(self.job, self.c["vocab_size"], self.seed, self.k)
         b = {"tokens": jnp.asarray(toks), "targets": jnp.asarray(tgts)}
@@ -157,17 +158,17 @@ class Trainer:
             self.params, self.opt_state, m = self.step_fn(
                 self.params, self.opt_state, b)
         self.k += 1
-        return m["loss"]
+        return m
 
     def check_steps(self) -> dict:
         """Steps 1 to ``CHECK_STEPS`` from a fresh state, and what the check
         reads of them. Also returns the seconds of the last one."""
-        losses = [float(self.dispatch())]
+        losses = [float(self.dispatch()["loss"])]
         b1 = self.opt.betas[0]
         grad = np.asarray(self._mu_norms(self.opt_state.mu)) / (1 - b1)
         for _ in range(CHECK_STEPS - 1):
             t = time.monotonic()
-            losses.append(float(self.dispatch()))
+            losses.append(float(self.dispatch()["loss"]))
         step_s = time.monotonic() - t
         change = np.asarray(change_norms(self.c)(
             self.params, bench_model.root_key(self.seed)))
@@ -187,6 +188,7 @@ class TrainWindow:
     losses: List[float] = field(default_factory=list)
     compiles: int = 0
     traced_steps: int = 0
+    traced_outputs: List[dict] = field(default_factory=list)
 
     @property
     def seconds(self) -> float:
@@ -198,12 +200,15 @@ def run_window(tr: Trainer, seconds: float, step_s: float, compile_log,
                trace_seconds: float = 0.0) -> TrainWindow:
     """Steps for ``seconds``, ``job["ahead_s"]`` seconds of them in flight.
     With ``trace_dir`` the window's first ``trace_seconds`` of steps are
-    traced, waited for, and the trace stopped before the rest are sent."""
+    traced, waited for, and the trace stopped before the rest are sent;
+    those steps' outputs are then read back, one ``device_get`` a step,
+    into ``traced_outputs``. Other steps' losses alone are read."""
     import jax
     w = TrainWindow(tr.c, tr.job)
     w.ahead = max(1, math.ceil(tr.job["ahead_s"] / max(step_s, 1e-3)))
     n0 = compile_log.programs
     pending = deque()
+    traced = []
     tracing = trace_dir is not None
     if tracing:
         jax.profiler.start_trace(trace_dir)
@@ -213,7 +218,10 @@ def run_window(tr: Trainer, seconds: float, step_s: float, compile_log,
         span = (jax.profiler.TraceAnnotation(STEP_SPAN) if tracing
                 else nullcontext())
         with span:
-            pending.append(tr.dispatch())
+            m = tr.dispatch()
+        pending.append(m["loss"])
+        if tracing:
+            traced.append(m)
         w.dispatch_s += time.monotonic() - t0
         w.steps += 1
         while len(pending) > w.ahead:
@@ -224,6 +232,7 @@ def run_window(tr: Trainer, seconds: float, step_s: float, compile_log,
             pending.clear()
             jax.profiler.stop_trace()
             w.traced_steps = w.steps
+            w.traced_outputs = [jax.device_get(m) for m in traced]
             tracing = False
     jax.block_until_ready((tr.params, tr.opt_state))
     w.losses += [float(x) for x in pending]
@@ -231,8 +240,16 @@ def run_window(tr: Trainer, seconds: float, step_s: float, compile_log,
     if tracing:
         jax.profiler.stop_trace()
         w.traced_steps = w.steps
+        w.traced_outputs = [jax.device_get(m) for m in traced]
     w.compiles = compile_log.programs - n0
     return w
+
+
+def step_counters(outputs: List[dict]) -> dict:
+    """Mean over the steps of each scalar output other than the loss."""
+    names = [k for k, v in (outputs[0] if outputs else {}).items()
+             if k != "loss" and np.ndim(v) == 0]
+    return {k: float(np.mean([o[k] for o in outputs])) for k in names}
 
 
 # --- the comparison --------------------------------------------------------
@@ -346,6 +363,9 @@ def measure(args, cell, c, job, devs, compile_log, rt, bench,
     if tr_dir is not None:
         import scopes
         import trace_reduce
+        ctx["step_counters"] = step_counters(w.traced_outputs)
+        log(f"step_counters over {len(w.traced_outputs)} traced steps: "
+            f"{ctx['step_counters']}")
         path = trace_reduce.find(str(tr_dir))
         if path is not None:
             trace = trace_reduce.load(path)
@@ -359,11 +379,12 @@ def measure(args, cell, c, job, devs, compile_log, rt, bench,
                 trace, scopes.op_names_from_hlo(scopes.step_hlo(tr)))
             ctx["scope_split"] = split
             if split and mods:
-                ms = dict(scopes.group_ms(split), unscoped=1e3 * sum(
-                    v for (sc, _), v in split.items()
-                    if sc == scopes.UNSCOPED),
-                    step_ops=1e3 * sum(split.values()),
-                    step_programs=1e3 * mods["seconds"] / mods["count"])
+                ms = dict(scopes.group_ms(split),
+                          unscoped=scopes.layer_ms(split, scopes.UNSCOPED),
+                          recompute=scopes.layer_ms(split, phase="recompute"),
+                          step_ops=1e3 * sum(split.values()),
+                          step_programs=1e3 * mods["seconds"]
+                          / mods["count"])
                 log("device ms per traced step, by scope: " + " ".join(
                     f"{k}={v}" for k, v in ms.items())
                     + f"; compiles for the HLO: {compile_log.programs - n0}")

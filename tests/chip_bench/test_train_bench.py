@@ -10,7 +10,8 @@ the step broken underneath (state unchanged, half of the batch left out)
 reads ``correct`` false. The reference's readings match a digest pinned
 before the architecture moved into ``arch/``; an architecture module in a
 new file elsewhere is enough for a correct run; a traced run reads the
-layer-scope split into its four metrics.
+layer-scope split into its four metrics; and a new part scope and a new
+step output reach new metric readers with no benchmark file edited.
 """
 import argparse
 import sys
@@ -223,9 +224,10 @@ def test_traced_run_reads_the_scope_split_of_the_compiled_step(
     the split itself is stood in for.)"""
     import jax
     import scopes
-    split = {("embed", "forward"): 1e-3, ("head", "backward"): 2e-3,
-             ("attn", "forward"): 3e-3, ("mlp", "backward"): 4e-3,
-             ("adamw", "forward"): 5e-3, ("unscoped", "forward"): 6e-3}
+    split = {("embed", "", "forward"): 1e-3, ("head", "", "backward"): 2e-3,
+             ("attn", "", "forward"): 3e-3, ("mlp", "", "backward"): 4e-3,
+             ("adamw", "", "forward"): 5e-3,
+             ("unscoped", "", "forward"): 6e-3}
     seen = {}
     log = harness.CompileLog(jax)
     step_hlo = scopes.step_hlo
@@ -256,20 +258,124 @@ def test_traced_run_reads_the_scope_split_of_the_compiled_step(
         "train_adamw_ms": pytest.approx(5.0)}
 
 
+READER_SPLIT = {
+    ("embed", "", "forward"): 0.001, ("head", "", "backward"): 0.010,
+    ("loss", "", "forward"): 0.002, ("attn", "", "backward"): 0.004,
+    ("attn", "", "recompute"): 0.003, ("mlp", "", "forward"): 0.020,
+    ("mlp", "", "backward"): 0.030, ("mlp", "", "recompute"): 0.0005,
+    ("adamw", "", "forward"): 0.005, ("unscoped", "", "forward"): 0.5}
+
+
 @pytest.mark.parametrize("name", SCOPE_METRICS)
 def test_scope_readers(name):
     import importlib
     import scopes
     read = importlib.import_module(f"metrics.{name}").read
-    split = {("embed", "forward"): 0.001, ("head", "backward"): 0.010,
-             ("loss", "forward"): 0.002, ("attn", "backward"): 0.004,
-             ("mlp", "forward"): 0.020, ("mlp", "backward"): 0.030,
-             ("adamw", "forward"): 0.005, ("unscoped", "forward"): 0.5}
-    want = {"train_vocab_ms": 13.0, "train_attn_ms": 4.0,
-            "train_mlp_ms": 50.0, "train_adamw_ms": 5.0}[name]
-    assert read(None, {"scope_split": split}) == pytest.approx(want)
-    assert read(None, {"scope_split": split}) == pytest.approx(
-        scopes.group_ms(split)[name])
+    want = {"train_vocab_ms": 13.0, "train_attn_ms": 7.0,
+            "train_mlp_ms": 50.5, "train_adamw_ms": 5.0}[name]
+    assert read(None, {"scope_split": READER_SPLIT}) == pytest.approx(want)
+    assert read(None, {"scope_split": READER_SPLIT}) == pytest.approx(
+        scopes.group_ms(READER_SPLIT)[name])
     assert read(None, {}) is None
     assert read(None, {"scope_split": None}) is None
-    assert read(None, {"scope_split": {("unscoped", "forward"): 0.5}}) is None
+    assert read(None, {"scope_split": {("unscoped", "", "forward"): 0.5}}
+                ) is None
+
+
+def test_recompute_reader():
+    from metrics import train_recompute_ms
+    read = train_recompute_ms.read
+    assert read(None, {"scope_split": READER_SPLIT}) == pytest.approx(3.5)
+    assert read(None, {}) is None
+    assert read(None, {"scope_split": None}) is None
+    assert read(None, {"scope_split": {("attn", "", "backward"): 0.5}}
+                ) is None
+
+
+def test_step_counters_are_the_mean_of_each_scalar_but_the_loss():
+    outs = [{"loss": 5.0, "grad_norm": np.float32(2.0), "lr": 1e-4,
+             "hist": np.ones(3)},
+            {"loss": 4.0, "grad_norm": np.float32(4.0), "lr": 2e-4,
+             "hist": np.ones(3)}]
+    assert train.step_counters(outs) == {"grad_norm": pytest.approx(3.0),
+                                         "lr": pytest.approx(1.5e-4)}
+    assert train.step_counters([]) == {}
+
+
+def test_a_new_part_scope_and_counter_need_only_new_files(
+        tmp_path, monkeypatch, capsys):
+    """A stand-in program change (the MLP's body under a part scope that
+    the program declares in ``PARTS``, and one more scalar among the
+    step's outputs), an architecture module and two metric readers in a
+    new directory: a traced run is correct and reports both readings. The
+    compiled step's HLO names the part under ``mlp``. (A CPU trace has no
+    device ops, so the split is stood in for: a millisecond for each
+    instruction of the compiled step, by its ``op_name``.)"""
+    import jax
+    import jax.numpy as jnp
+    import scopes
+    from repro.distributed import steps as program_steps
+    from repro.models import transformer
+    from repro.obs import scopes as program_scopes
+
+    monkeypatch.setattr(program_scopes, "PARTS", ("experts",), raising=False)
+    mlp = transformer.mlp
+
+    def mlp_in_part(*a, **k):
+        with jax.named_scope("experts"):
+            return mlp(*a, **k)
+    monkeypatch.setattr(transformer, "mlp", mlp_in_part)
+    build = program_steps.build_train_step
+
+    def build_with_counter(*a, **k):
+        step = build(*a, **k)
+
+        def counted(params, opt_state, batch_):
+            params, opt_state, m = step(params, opt_state, batch_)
+            m["tokens_routed"] = jnp.asarray(batch_["tokens"].size,
+                                             jnp.float32)
+            return params, opt_state, m
+        return counted
+    monkeypatch.setattr(program_steps, "build_train_step", build_with_counter)
+
+    (tmp_path / "arch").mkdir()
+    (tmp_path / "arch" / "dense_gqa_parts.py").write_text(
+        "from arch.dense_gqa import (layer, layer_matmul_params,\n"
+        "                            layer_weights, program_config)\n")
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "expert_part_ms.py").write_text(
+        "from metrics._common import split_ms\n\n\n"
+        "def read(w, ctx):\n"
+        "    return split_ms(ctx, \"mlp\", \"experts\")\n")
+    (tmp_path / "metrics" / "tokens_routed.py").write_text(
+        "def read(w, ctx):\n"
+        "    return ctx.get(\"step_counters\", {}).get(\"tokens_routed\")\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+
+    seen = {}
+
+    def by_scope(trace, op_names):
+        seen["keys"] = keys = [scopes.scope_of(v) for v in op_names.values()]
+        out = {}
+        for k in keys:
+            out[k] = out.get(k, 0.0) + 1e-3
+        return out
+    monkeypatch.setattr(scopes, "by_scope", by_scope)
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path / "trace")
+    c = dict(TINY, name="tiny-parts", reference="dense_gqa_parts")
+    bench = {"end_to_end": [], "per_layer": [
+        {"name": "expert_part_ms", "unit": "ms"},
+        {"name": "tokens_routed", "unit": "tokens"}]}
+    result = _measure(3, c=c, trace=1, bench=bench)
+    assert result["correct"] is True, result["checks"]
+    n_part = sum(1 for k in seen["keys"] if k[:2] == ("mlp", "experts"))
+    assert {ph for sc, pt, ph in seen["keys"] if pt == "experts"} == {
+        "forward", "backward", "recompute"}
+    assert {sc for sc, pt, _ in seen["keys"] if pt} == {"mlp"}
+    assert {k: v["value"] for k, v in result["metrics"].items()} == {
+        "expert_part_ms": pytest.approx(float(n_part)),
+        "tokens_routed": pytest.approx(JOB["batch"] * JOB["seq_len"])}
+    line = next(ln for ln in capsys.readouterr().err.splitlines()
+                if "step_counters" in ln)
+    assert all(k in line for k in ("grad_norm", "lr", "tokens_routed"))
+    assert "loss" not in line
